@@ -40,9 +40,7 @@ import platform
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -31,9 +31,6 @@ import platform
 import sys
 import time
 
-# no JAX_PLATFORMS=cpu default here (unlike the CPU-only benches): the
-# compiled >= 1.5x decode gate must engage when a TPU backend is present;
-# CI pins cpu explicitly in the workflow env
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -7,14 +7,12 @@ Prints ``name,us_per_call,derived`` CSV rows.
 """
 from __future__ import annotations
 
-import os
 import sys
 import time
 import traceback
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from benchmarks import common  # noqa: E402
+from benchmarks import common
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "motivation": ("benchmarks.bench_motivation", "Fig. 2/3/4 + Table 2"),
@@ -34,6 +32,7 @@ SUITES = {
 
 def main() -> None:
     wanted = sys.argv[1:] or list(SUITES)
+    use_compile_cache()
     common.header()
     failures = []
     for key in wanted:

@@ -11,21 +11,18 @@ at the end.
 
   PYTHONPATH=src python examples/daemon.py
 """
-import os
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import asyncio
+import json
 
-import asyncio  # noqa: E402
-import json  # noqa: E402
-
-from repro.cluster.catalog import Cluster, InstanceType  # noqa: E402
-from repro.core.agora import Agora  # noqa: E402
-from repro.core.dag import DAG, Task, TaskOption  # noqa: E402
-from repro.core.objectives import Goal  # noqa: E402
-from repro.core.session import (SLA_BEST_EFFORT, SLA_GUARANTEED,  # noqa: E402
+from repro.cluster.catalog import Cluster, InstanceType
+from repro.core.agora import Agora
+from repro.core.dag import DAG, Task, TaskOption
+from repro.core.objectives import Goal
+from repro.core.session import (SLA_BEST_EFFORT, SLA_GUARANTEED,
                                 PlanRequest)
-from repro.core.vectorized import VecConfig  # noqa: E402
-from repro.flow.daemon import (DaemonConfig, LoadShedError,  # noqa: E402
+from repro.core.vectorized import VecConfig
+from repro.flow.daemon import (DaemonConfig, LoadShedError,
                                PlannerHTTPServer, PlannerService, PoolSpec,
                                dag_to_json)
 

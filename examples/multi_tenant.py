@@ -14,9 +14,6 @@ tenants arrive.
   PYTHONPATH=src python examples/multi_tenant.py --shared
 """
 import argparse
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from repro.cluster.catalog import Cluster, alibaba_cluster
 from repro.core.agora import Agora

@@ -2,9 +2,6 @@
 
   PYTHONPATH=src python examples/quickstart.py
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from repro.cluster.catalog import paper_cluster
 from repro.cluster.workloads import dag1

@@ -3,9 +3,6 @@ three architecture families (dense GQA, RWKV6 state-based, Mamba2 hybrid).
 
   PYTHONPATH=src python examples/serve_batch.py
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from repro.launch.serve_model import serve
 

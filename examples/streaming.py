@@ -10,9 +10,6 @@ replayed through the FIFO no-SLA baseline for comparison.
 
   PYTHONPATH=src python examples/streaming.py
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 

@@ -9,8 +9,6 @@ import argparse
 import os
 import tempfile
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np
 
 from repro.cluster.catalog import tpu_cluster

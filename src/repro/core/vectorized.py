@@ -512,7 +512,6 @@ def _run_sa_many_sharded_jit(per_problem, caps, goal_w, ref_M, ref_C, dl,
     ``mesh`` rides in the static JIT signature, so re-planning inside a
     P bucket reuses the live cache entry (same zero-retrace contract as
     the unsharded path)."""
-    from repro.compat import shard_map
     ap, ac = mesh.axis_names
     chain_devs = mesh.shape[ac]
 
@@ -542,11 +541,11 @@ def _run_sa_many_sharded_jit(per_problem, caps, goal_w, ref_M, ref_C, dl,
         # (P, S) traces shard with their problems; the chain axis was
         # already reduced globally inside the scan (pmin/pmean)
         out_specs.update(tel_best_e=P(ap), tel_accept=P(ap), tel_mig=P(ap))
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=((P(ap),) * len(per_problem), P(ap), P(ap), P(ap), P(ap),
                   P(ap), pbj, pbj, P(ap), P()),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     return fn(per_problem, goal_w, ref_M, ref_C, dl, dl_w, opt0, prio0,
               keys, caps)
 
@@ -638,6 +637,41 @@ def _attach_telemetry(sols: List[Solution], state, cfg: VecConfig) -> None:
                              iters=cfg.iters, chains=cfg.chains)
 
 
+def many_solve_call(problems: Sequence[FlatProblem], cluster: Cluster,
+                    cfg: VecConfig, ref_M: np.ndarray, ref_C: np.ndarray,
+                    goals: Sequence[Goal], bucket_p=None, mesh=None):
+    """The device program of ``vectorized_anneal_many`` as ``(jitted fn,
+    positional args)``: ``fn(*args)`` is the solve, and
+    ``fn.lower(*args).compile()`` compiles it ahead of time."""
+    if mesh is not None:
+        ap, ac = mesh.axis_names
+        # bucket the problem axis up to the mesh: power-of-two device
+        # counts always divide the power-of-two bucket, and padded slots
+        # are provably inert, so meshing never changes the plans
+        bucket_p = max(int(bucket_p or 1), mesh.shape[ap])
+    packed = pack_problems(problems, cluster.num_resources, bucket_p=bucket_p)
+    P_pad = packed.padded_problems
+    if mesh is not None:
+        assert P_pad % mesh.shape[ap] == 0, \
+            f"problem bucket {P_pad} not divisible by mesh axis " \
+            f"{ap}={mesh.shape[ap]}"
+        assert cfg.chains % mesh.shape[ac] == 0, (cfg.chains, mesh.shape[ac])
+    ref_Mp, ref_Cp = _pad_refs(ref_M, ref_C, P_pad)
+    goal_w, dl, dl_w = _goal_arrays(goals, P_pad)
+    bdp = BatchedDeviceProblem.build(packed, cluster, ref_Mp, cfg)
+
+    opt0, prio0, pkeys = _init_chains(packed, cfg)
+
+    per_problem = (bdp.dur_bins, bdp.demands, bdp.costs, bdp.n_opts,
+                   bdp.pred_mask, bdp.release_bins, bdp.dt, bdp.n_real)
+    args = (per_problem, bdp.caps, goal_w, jnp.asarray(ref_Mp, jnp.float32),
+            jnp.asarray(ref_Cp, jnp.float32), dl, dl_w, cfg, bdp.T, opt0,
+            prio0, pkeys)
+    if mesh is None:
+        return _run_sa_many_jit, args
+    return _run_sa_many_sharded_jit, args + (mesh,)
+
+
 def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
                            goal: Goal, cfg: Optional[VecConfig] = None,
                            refs: Optional[Sequence[Tuple[float, float]]] = None,
@@ -671,33 +705,9 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
     ref_M = np.asarray([r[0] for r in refs])
     ref_C = np.asarray([r[1] for r in refs])
 
-    if mesh is not None:
-        ap, ac = mesh.axis_names
-        # bucket the problem axis up to the mesh: power-of-two device
-        # counts always divide the power-of-two bucket, and padded slots
-        # are provably inert, so meshing never changes the plans
-        bucket_p = max(int(bucket_p or 1), mesh.shape[ap])
-    packed = pack_problems(problems, cluster.num_resources, bucket_p=bucket_p)
-    P_pad = packed.padded_problems
-    if mesh is not None:
-        assert P_pad % mesh.shape[ap] == 0, \
-            f"problem bucket {P_pad} not divisible by mesh axis " \
-            f"{ap}={mesh.shape[ap]}"
-        assert cfg.chains % mesh.shape[ac] == 0, (cfg.chains, mesh.shape[ac])
-    ref_Mp, ref_Cp = _pad_refs(ref_M, ref_C, P_pad)
-    goal_w, dl, dl_w = _goal_arrays(goals, P_pad)
-    bdp = BatchedDeviceProblem.build(packed, cluster, ref_Mp, cfg)
-
-    opt0, prio0, pkeys = _init_chains(packed, cfg)
-
-    per_problem = (bdp.dur_bins, bdp.demands, bdp.costs, bdp.n_opts,
-                   bdp.pred_mask, bdp.release_bins, bdp.dt, bdp.n_real)
-    run = (_run_sa_many_jit if mesh is None
-           else partial(_run_sa_many_sharded_jit, mesh=mesh))
-    state = run(per_problem, bdp.caps, goal_w,
-                jnp.asarray(ref_Mp, jnp.float32),
-                jnp.asarray(ref_Cp, jnp.float32),
-                dl, dl_w, cfg, bdp.T, opt0, prio0, pkeys)
+    run, args = many_solve_call(problems, cluster, cfg, ref_M, ref_C, goals,
+                                bucket_p=bucket_p, mesh=mesh)
+    state = run(*args)
 
     best_idx = np.asarray(jnp.argmin(state["best_e"], axis=1))     # (P,)
     best_opt = np.asarray(state["best_opt"])                        # (P, B, J)
@@ -959,7 +969,6 @@ def _run_sa_shared_sharded_jit(dp_arrays, dp_static, n_real, goal_w, ref_M,
     size 1 is bit-identical to the single-device coupled solve; with >1
     shards each device folds its axis index into every per-tenant key
     (mirroring the isolated sharded path)."""
-    from repro.compat import shard_map
     ap, ac = mesh.axis_names
     chain_devs = mesh.shape[ac]
 
@@ -983,13 +992,46 @@ def _run_sa_shared_sharded_jit(dp_arrays, dp_static, n_real, goal_w, ref_M,
         # chain-axis collectives inside the scan make the (P, S) traces
         # replicated across chain shards (the only sharded axis here)
         out_specs.update(tel_best_e=P(), tel_accept=P(), tel_mig=P())
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=((P(),) * len(dp_arrays), P(), P(), P(), P(), P(), P(),
                   pbj, pbj, P()),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     return fn(dp_arrays, n_real, goal_w, ref_M, ref_C, dl, dl_w,
               opt0, prio0, pkeys)
+
+
+def shared_solve_call(problems: Sequence[FlatProblem], cluster: Cluster,
+                      cfg: VecConfig, ref_M: np.ndarray, ref_C: np.ndarray,
+                      goals: Sequence[Goal], bucket_p=None, mesh=None):
+    """The device program of ``vectorized_anneal_shared`` as ``(jitted fn,
+    positional args, SharedDeviceProblem, joint FlatProblem)`` — see
+    ``many_solve_call``."""
+    from repro.core.annealer import reference_point
+    packed = pack_problems(problems, cluster.num_resources,
+                           shared_capacity=True, bucket_p=bucket_p)
+    layout = packed.shared_layout()
+    joint = layout.joint_problem()
+    joint_ref = reference_point(joint, cluster)
+    sdp = SharedDeviceProblem.build(layout, cluster, joint_ref[0], cfg)
+    P_pad = packed.padded_problems
+    ref_Mp, ref_Cp = _pad_refs(ref_M, ref_C, P_pad)
+    goal_w, dl, dl_w = _goal_arrays(goals, P_pad)
+
+    opt0, prio0, pkeys = _init_chains(packed, cfg)
+
+    if mesh is not None:
+        ac = mesh.axis_names[1]
+        assert cfg.chains % mesh.shape[ac] == 0, (cfg.chains, mesh.shape[ac])
+    dp_arrays = (sdp.dp.dur_bins, sdp.dp.demands, sdp.dp.costs, sdp.dp.n_opts,
+                 sdp.dp.pred_mask, sdp.dp.release_bins, sdp.dp.caps,
+                 jnp.float32(sdp.dp.dt))
+    args = (dp_arrays, (sdp.dp.T,), sdp.n_real, goal_w,
+            jnp.asarray(ref_Mp, jnp.float32), jnp.asarray(ref_Cp, jnp.float32),
+            dl, dl_w, cfg, opt0, prio0, pkeys)
+    if mesh is None:
+        return _run_sa_shared_jit, args, sdp, joint
+    return _run_sa_shared_sharded_jit, args + (mesh,), sdp, joint
 
 
 def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
@@ -1033,32 +1075,12 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     ref_M = np.asarray([r[0] for r in refs])
     ref_C = np.asarray([r[1] for r in refs])
 
-    packed = pack_problems(problems, cluster.num_resources,
-                           shared_capacity=True, bucket_p=bucket_p)
-    layout = packed.shared_layout()
-    joint = layout.joint_problem()
-    joint_ref = reference_point(joint, cluster)
-    sdp = SharedDeviceProblem.build(layout, cluster, joint_ref[0], cfg)
-    P_n = packed.num_problems
-    P_pad = packed.padded_problems
-    ref_Mp, ref_Cp = _pad_refs(ref_M, ref_C, P_pad)
-    goal_w, dl, dl_w = _goal_arrays(goals, P_pad)
-    ref_Mj = jnp.asarray(ref_Mp, jnp.float32)
-    ref_Cj = jnp.asarray(ref_Cp, jnp.float32)
-
-    opt0, prio0, pkeys = _init_chains(packed, cfg)
-
-    if mesh is not None:
-        ac = mesh.axis_names[1]
-        assert cfg.chains % mesh.shape[ac] == 0, (cfg.chains, mesh.shape[ac])
-    dp_arrays = (sdp.dp.dur_bins, sdp.dp.demands, sdp.dp.costs, sdp.dp.n_opts,
-                 sdp.dp.pred_mask, sdp.dp.release_bins, sdp.dp.caps,
-                 jnp.float32(sdp.dp.dt))
-    run = (_run_sa_shared_jit if mesh is None
-           else partial(_run_sa_shared_sharded_jit, mesh=mesh))
-    state = run(dp_arrays, (sdp.dp.T,), sdp.n_real,
-                goal_w, ref_Mj, ref_Cj, dl, dl_w,
-                cfg, opt0, prio0, pkeys)
+    run, args, sdp, joint = shared_solve_call(
+        problems, cluster, cfg, ref_M, ref_C, goals, bucket_p=bucket_p,
+        mesh=mesh)
+    state = run(*args)
+    _, _, _, goal_w, ref_Mj, ref_Cj, dl, dl_w, _, opt0, *_ = args
+    P_pad = opt0.shape[0]
 
     best_idx = np.asarray(jnp.argmin(state["best_e"], axis=1))      # (P',)
     best_opt = np.asarray(state["best_opt"])                        # (P', B, J)
@@ -1079,8 +1101,10 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     opt_self = jnp.asarray(best_opt[np.arange(P_pad), best_idx])    # (P', J)
     prio_self = jnp.asarray(best_prio[np.arange(P_pad), best_idx])
     b_star = int(np.asarray(jnp.argmin(state["jbest_sum"])))
-    opt_coh = state["jbest_opt"][:, b_star]
-    prio_coh = state["jbest_prio"][:, b_star]
+    # on the host: under a mesh the snapshot is sharded over chains, and a
+    # Pallas decode cannot take sharded operands outside shard_map
+    opt_coh = np.asarray(state["jbest_opt"][:, b_star])
+    prio_coh = np.asarray(state["jbest_prio"][:, b_star])
     e2, _, _ = shared_chain_energy(
         sdp, goal_w, ref_Mj, ref_Cj, dl, dl_w,
         jnp.stack([opt_self, opt_coh], axis=1),         # (P', 2, J)
@@ -1178,11 +1202,10 @@ def vectorized_anneal(problem: FlatProblem, cluster: Cluster, goal: Goal,
                       opt0, prio0, k3, axis_name=axis)
         return tuple(st[k] for k in keys)  # scalars (T) stay device-local
 
-    from repro.compat import shard_map
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis),) * 6))
+        out_specs=(P(axis),) * 6, check_vma=False))
     vals = fn(opt0, prio0)
     state = dict(zip(keys, vals))
 

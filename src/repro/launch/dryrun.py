@@ -22,7 +22,6 @@ import traceback    # noqa: E402
 import jax          # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro import compat                             # noqa: E402
 from repro import roofline as rl                     # noqa: E402
 from repro.configs import ARCH_IDS, get_config       # noqa: E402
 from repro.launch import shapes as shp               # noqa: E402
@@ -77,7 +76,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.monotonic() - t0 - t_lower
 
         chips = int(mesh.devices.size)
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         # cost_analysis is per-partition under SPMD (calibrated; see
         # roofline.py docstring) -> scale to global.
         flops = float(cost.get("flops", 0.0)) * chips
@@ -132,7 +131,7 @@ def _cell_costs(arch: str, shape_name: str, mesh, layers: int,
                                             opt_overrides=ov)
     compiled = lowered.compile()
     chips = int(mesh.devices.size)
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = rl.parse_collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)) * chips,

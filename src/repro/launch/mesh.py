@@ -10,26 +10,33 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh as _mk
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axis types (its default is Explicit):
+    shardings follow the program's annotations and shard_map specs."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, model_parallel: int = 1):
     """Elastic-scaling helper: best (data, model) mesh for an arbitrary
     device count (used by the flow executor when the pool resizes)."""
     assert devices % model_parallel == 0, (devices, model_parallel)
-    return _mk((devices // model_parallel, model_parallel), ("data", "model"))
+    return make_mesh((devices // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 def make_solver_mesh(devices=None):
     """1-D chains mesh for the distributed annealer."""
     devices = devices if devices is not None else jax.devices()
-    return _mk((len(devices),), ("chains",))
+    return make_mesh((len(devices),), ("chains",))
 
 
 def make_planner_mesh(chains: int = 1, devices=None):
@@ -49,9 +56,9 @@ def make_planner_mesh(chains: int = 1, devices=None):
     assert chains >= 1 and n % chains == 0, (n, chains)
     prob = 1 << ((n // chains).bit_length() - 1)
     if not explicit and prob * chains == n:
-        return _mk((prob, chains), ("prob", "chain"))
+        return make_mesh((prob, chains), ("prob", "chain"))
     # an explicit device list (or a clamped prob axis) must pin the mesh
-    # to exactly those devices — _mk builds over the process-global set
+    # to exactly those devices — make_mesh builds over the process-global set
     import numpy as np
     sub = np.asarray(devices[:prob * chains]).reshape(prob, chains)
     return jax.sharding.Mesh(sub, ("prob", "chain"))

@@ -25,6 +25,7 @@ from repro.core.objectives import Goal
 from repro.core.vectorized import VecConfig
 from repro.flow.daemon import (DaemonConfig, PlannerHTTPServer,
                                PlannerService, PoolSpec)
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs.sink import NULL, JsonlSink
 
 
@@ -101,6 +102,7 @@ def main(argv=None) -> None:
     ap.add_argument("--iters", type=int, default=200)
     ap.add_argument("--grid", type=int, default=128)
     args = ap.parse_args(argv)
+    use_compile_cache()
     try:
         asyncio.run(_serve(args))
     except KeyboardInterrupt:

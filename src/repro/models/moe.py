@@ -103,10 +103,9 @@ def moe_layer(p, x, cfg: ModelConfig, mesh):
     # Tokens are replicated over the model axis (baseline: every TP rank routes
     # the same tokens); outputs are therefore replicated too, but that fact is
     # not statically inferable through all_to_all -> check_vma=False.
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(xspec, P(None, None), espec, espec, espec),
-        out_specs=(xspec, P()),
+        out_specs=(xspec, P()), check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

@@ -88,11 +88,10 @@ def pp_apply_blocks(cfg: ModelConfig, params_blocks, x, positions, mesh,
         return out
 
     pspec = jax.tree.map(lambda _: P(STAGE_AXIS), staged)
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, dp if dp else None), pspec),
-        out_specs=P(None, dp if dp else None))
+        out_specs=P(None, dp if dp else None), check_vma=False)
     out = fn(xs, staged)
     return out.reshape(B, *x.shape[1:])
 

@@ -36,8 +36,7 @@ def _quantize(xf):
 def ring_allreduce_int8(x, axis: str):
     """Inside shard_map: mean-reduce ``x`` over ``axis``; int8 on the wire.
     Returns f32, identical on every replica."""
-    from repro.compat import axis_size
-    K = axis_size(axis)
+    K = jax.lax.axis_size(axis)
     xf = x.astype(jnp.float32)
     if K == 1:
         return xf

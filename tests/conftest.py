@@ -10,5 +10,5 @@ import pytest
 @pytest.fixture(scope="session")
 def mesh11():
     """Trivial (1,1) mesh with production axis names for smoke tests."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh((1, 1), ("data", "model"))

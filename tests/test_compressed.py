@@ -20,7 +20,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.optim.compressed import ring_allreduce_int8
 
     mesh = make_mesh((8,), ("dp",))
@@ -32,8 +32,8 @@ SCRIPT = textwrap.dedent("""
         comp = ring_allreduce_int8(xl, "dp")
         return exact, comp
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=P("dp"),
-                           out_specs=(P("dp"), P("dp"))))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("dp"),
+                               out_specs=(P("dp"), P("dp")), check_vma=False))
     exact, comp = fn(x)
     exact, comp = np.asarray(exact), np.asarray(comp)
     rel = float(np.linalg.norm(comp - exact) / np.linalg.norm(exact))
@@ -52,8 +52,8 @@ SCRIPT = textwrap.dedent("""
         v, e = compressed_reduce({"w": xl}, {"w": el}, "dp")
         return v["w"], e["w"]
 
-    fn2 = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                            out_specs=(P("dp"), P("dp"))))
+    fn2 = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                                out_specs=(P("dp"), P("dp")), check_vma=False))
     err = jnp.zeros_like(x)
     acc = np.zeros_like(exact)
     T = 8

@@ -28,7 +28,7 @@ SCRIPT = textwrap.dedent("""
 
     results = {}
 
-    from repro.compat import make_mesh as mk, mesh_context
+    from repro.launch.mesh import make_mesh as mk
 
     # ---- pipeline parallelism ------------------------------------------
     cfg = get_config("smollm-360m", smoke=True).replace(
@@ -43,7 +43,7 @@ SCRIPT = textwrap.dedent("""
     base, _ = jax.jit(model.loss)(params, batch)
     model_pp = Model(cfg, mesh=mesh_pp)
     pp = pp_loss_fn(model_pp, mesh_pp, n_micro=4)
-    with mesh_context(mesh_pp):
+    with jax.set_mesh(mesh_pp):
         ppl, _ = jax.jit(pp)(params, batch)
     results["pp"] = [float(base), float(ppl)]
 

@@ -55,10 +55,15 @@ def _random_dags(seed, P):
 
 
 def _agora(solver="vectorized", **kw):
+    # the exact inner solver stops on its node budget, never on the wall
+    # clock, so two host-anneal runs agree however loaded the machine is
     return Agora(_cluster(), goal=Goal.balanced(), solver=solver,
                  vec_cfg=CFG,
                  anneal_cfg=AnnealConfig(min_iters=60, max_iters=90,
-                                         patience=30, seed=0), **kw)
+                                         patience=30, seed=0,
+                                         exact_node_budget=5_000,
+                                         exact_time_budget=math.inf),
+                 **kw)
 
 
 def _assert_plans_equal(legacy, via_session):

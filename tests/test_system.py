@@ -96,7 +96,7 @@ MINI_DRYRUN = textwrap.dedent("""
     import repro.launch.dryrun as dr
 
     def mk(multi_pod=False):
-        return lm._mk((2, 2, 2) if multi_pod else (4, 2),
+        return lm.make_mesh((2, 2, 2) if multi_pod else (4, 2),
                       ("pod", "data", "model") if multi_pod else ("data", "model"))
     dr.make_production_mesh = mk
 
