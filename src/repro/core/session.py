@@ -47,6 +47,7 @@ from repro.obs import events as obs
 from repro.obs.aggregate import finite_or_none
 from repro.obs.events import Event
 from repro.obs.sink import as_sink
+from repro.obs.spans import SOLVE_PREPARE, recorder
 
 # SLA classes (the streaming control plane re-exports these)
 SLA_GUARANTEED = "guaranteed"
@@ -453,18 +454,26 @@ class PlannerSession:
         from repro.core.agora import Plan
         from repro.core.annealer import reference_point
 
+        # host spans of a live batch (warm-up solves are not narrated):
+        # solve.prepare here, the engine's phases inside plan_solved's span
+        spans = recorder(self.sink and not warming)
+        if spans:
+            spans.mark()
         cluster = self._cluster_for(capacity)
         problems = [flatten(list(r.dags), cluster.num_resources)
                     for r in requests]
         refs = [r.ref if r.ref is not None else reference_point(p, cluster)
                 for r, p in zip(requests, problems)]
+        if spans:
+            spans.lap(SOLVE_PREPARE)
         goals = [r.goal or self.goal for r in requests]
         bucket_p = self.bucket_p if bucket_override is None else bucket_override
         batch = SolveBatch(
             spec=self.spec, problems=problems, cluster=cluster,
             goal=self.goal, goals=goals, refs=refs, cfg=self.vec_cfg,
             bucket_p=bucket_p, mesh=self._planner_mesh(),
-            solve_single=lambda p, r, g: self._solve_single(p, r, g, cluster))
+            solve_single=lambda p, r, g: self._solve_single(p, r, g, cluster),
+            spans=spans)
 
         with self._lock:
             n0 = self.engine.cache_size()
@@ -490,7 +499,7 @@ class PlannerSession:
                  for s in sols]
         trace_ids = [r.trace for r in requests if r.trace is not None]
         if self.sink:
-            self._emit_dispatch(traced, dt, bucket=bucket, jmax=jmax,
+            self._emit_dispatch(traced, bucket=bucket, jmax=jmax,
                                 omax=omax, warming=warming,
                                 trace_ids=trace_ids)
             if not warming:
@@ -500,6 +509,8 @@ class PlannerSession:
                     data["trace_ids"] = trace_ids
                 self.sink.emit(Event(
                     obs.PLAN_SOLVED, ts=time.monotonic(), data=data))
+                for event in spans.events(trace_ids=trace_ids):
+                    self.sink.emit(event)
                 if any(c is not None for c in convs):
                     # exactly ONE solve_profile per live engine dispatch:
                     # the convergence roll-up of every telemetry-bearing
@@ -507,8 +518,7 @@ class PlannerSession:
                     profiles = [dict(tenant=req.name, **c.summary())
                                 for req, c in zip(requests, convs)
                                 if c is not None]
-                    pdata = {"n": len(requests), "bucket": bucket,
-                             "seconds": dt, "profiles": profiles}
+                    pdata = {"profiles": profiles}
                     if trace_ids:
                         pdata["trace_ids"] = trace_ids
                     self.sink.emit(Event(
@@ -521,16 +531,16 @@ class PlannerSession:
                 for i, (plan, req, conv)
                 in enumerate(zip(plans, requests, convs))]
 
-    def _emit_dispatch(self, traced: bool, seconds: float, *, bucket: int,
+    def _emit_dispatch(self, traced: bool, *, bucket: int,
                        jmax: Optional[int] = None,
                        omax: Optional[int] = None,
                        warming: bool = False,
                        trace_ids: Optional[List[str]] = None) -> None:
         """Exactly one of ``bucket_traced`` / ``cache_hit`` per engine
-        dispatch."""
+        dispatch (its wall seconds ride the ``plan_solved`` event)."""
         if not self.sink:
             return
-        data = {"bucket": bucket, "seconds": seconds, "warming": warming}
+        data = {"bucket": bucket, "warming": warming}
         if jmax is not None:
             data["jmax"], data["omax"] = jmax, omax
         if trace_ids:
@@ -657,7 +667,7 @@ class PlannerSession:
             traced = self._single_cache_size() > n0
             self._account(1, traced, dt)
         if self.sink:
-            self._emit_dispatch(traced, dt, bucket=1)
+            self._emit_dispatch(traced, bucket=1)
             self.sink.emit(Event(
                 obs.PLAN_SOLVED, ts=time.monotonic(),
                 data={"kind": "plan_joint", "n": len(tuple(dags)),
@@ -705,7 +715,7 @@ class PlannerSession:
             traced = self._single_cache_size() > n0
             self._account(1, traced, dt, replan=True)
         if self.sink:
-            self._emit_dispatch(traced, dt, bucket=1)
+            self._emit_dispatch(traced, bucket=1)
             self.sink.emit(Event(
                 obs.PLAN_SOLVED, ts=time.monotonic(),
                 data={"kind": "replan", "n": 1, "bucket": 1,

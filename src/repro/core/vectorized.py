@@ -43,6 +43,8 @@ from repro.core.objectives import Goal, Solution
 from repro.core.sgs import (schedule_cost, sgs_schedule,
                             validate_schedule_many)
 from repro.kernels import ops as kops
+from repro.obs.spans import (NULL_SPANS, SOLVE_DEVICE, SOLVE_PACK,
+                             SOLVE_RECHECK, SOLVE_SELECT, Spans)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,7 +131,8 @@ class SolveBatch:
     knobs. ``solve_single`` is the spec-faithful single-problem solver the
     sequential host engines loop over (built by the session so host
     fallbacks honor the same AnnealConfig / chains mesh the legacy front
-    door used)."""
+    door used). ``spans`` records the device engines' host phases
+    (``repro.obs.spans``); the falsy default records nothing."""
     spec: SolveSpec
     problems: List[FlatProblem]
     cluster: Cluster
@@ -140,6 +143,7 @@ class SolveBatch:
     bucket_p: object = None
     mesh: object = None
     solve_single: Optional[Callable] = None      # (problem, ref, goal) -> Solution
+    spans: Spans = NULL_SPANS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -676,7 +680,8 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
                            goal: Goal, cfg: Optional[VecConfig] = None,
                            refs: Optional[Sequence[Tuple[float, float]]] = None,
                            goals: Optional[Sequence[Goal]] = None,
-                           bucket_p=None, mesh=None) -> List[Solution]:
+                           bucket_p=None, mesh=None,
+                           spans: Spans = NULL_SPANS) -> List[Solution]:
     """Anneal P independent problems in one batched device solve.
 
     Returns one ``Solution`` per problem, each re-evaluated event-exactly on
@@ -691,10 +696,16 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
     ``shard_map``: the problem axis over the first mesh axis, chains over
     the second. The problem axis is auto-bucketed to cover the mesh, and a
     chains axis of size 1 is bit-identical to the single-device solve.
+
+    ``spans`` records ``solve.pack``, ``solve.device`` and
+    ``solve.recheck``, which tile the call; the device solve ends at the
+    fetch of the incumbents that already blocks, so recording adds no sync.
     """
     cfg = cfg or VecConfig()
     problems = list(problems)
     t_start = time.monotonic()
+    if spans:
+        spans.mark()
     if refs is None:
         from repro.core.annealer import reference_point
         refs = [reference_point(p, cluster) for p in problems]
@@ -707,11 +718,15 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
 
     run, args = many_solve_call(problems, cluster, cfg, ref_M, ref_C, goals,
                                 bucket_p=bucket_p, mesh=mesh)
+    if spans:
+        spans.lap(SOLVE_PACK)
     state = run(*args)
 
     best_idx = np.asarray(jnp.argmin(state["best_e"], axis=1))     # (P,)
     best_opt = np.asarray(state["best_opt"])                        # (P, B, J)
     best_prio = np.asarray(state["best_prio"])
+    if spans:
+        spans.lap(SOLVE_DEVICE)
     elapsed = time.monotonic() - t_start
 
     sols = []
@@ -729,6 +744,8 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
         sol.solve_seconds = elapsed   # batch wall time: one dispatch for all P
         sols.append(sol)
     _attach_telemetry(sols, state, cfg)
+    if spans:
+        spans.lap(SOLVE_RECHECK)
     return sols
 
 
@@ -1038,7 +1055,8 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
                              goal: Goal, cfg: Optional[VecConfig] = None,
                              refs: Optional[Sequence[Tuple[float, float]]] = None,
                              goals: Optional[Sequence[Goal]] = None,
-                             bucket_p=None, mesh=None
+                             bucket_p=None, mesh=None,
+                             spans: Spans = NULL_SPANS
                              ) -> Tuple[List[Solution], List[str]]:
     """Anneal P tenant problems against ONE shared cluster capacity.
 
@@ -1061,10 +1079,16 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     ``mesh`` (the 2-axis planner mesh) shards the CHAIN axis over its
     second axis — the coupled decode is joint over problems, so the first
     axis stays replicated here (see ``_run_sa_shared_sharded_jit``).
+
+    ``spans`` records ``solve.pack``, ``solve.device``, ``solve.select``
+    (the coupled re-evaluation of the two assemblies) and
+    ``solve.recheck``, which tile the call (see ``vectorized_anneal_many``).
     """
     cfg = cfg or VecConfig()
     problems = list(problems)
     t_start = time.monotonic()
+    if spans:
+        spans.mark()
     from repro.core.annealer import reference_point
     if refs is None:
         refs = [reference_point(p, cluster) for p in problems]
@@ -1078,6 +1102,8 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     run, args, sdp, joint = shared_solve_call(
         problems, cluster, cfg, ref_M, ref_C, goals, bucket_p=bucket_p,
         mesh=mesh)
+    if spans:
+        spans.lap(SOLVE_PACK)
     state = run(*args)
     _, _, _, goal_w, ref_Mj, ref_Cj, dl, dl_w, _, opt0, *_ = args
     P_pad = opt0.shape[0]
@@ -1085,6 +1111,8 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     best_idx = np.asarray(jnp.argmin(state["best_e"], axis=1))      # (P',)
     best_opt = np.asarray(state["best_opt"])                        # (P', B, J)
     best_prio = np.asarray(state["best_prio"])
+    if spans:
+        spans.lap(SOLVE_DEVICE)
 
     # two candidate assemblies (both span the FULL padded batch — the
     # coupled decode is shaped for it; padding rows are inert and add the
@@ -1115,6 +1143,8 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
         opt_pick, prio_pick = np.asarray(opt_coh), np.asarray(prio_coh)
     else:
         opt_pick, prio_pick = np.asarray(opt_self), np.asarray(prio_self)
+    if spans:
+        spans.lap(SOLVE_SELECT)
 
     # re-evaluate the winning assembly event-exactly with ONE host SGS pass
     # under the global capacity
@@ -1147,6 +1177,8 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     joint_errors = validate_schedule_many(problems, ois, starts, finishes,
                                           cluster.caps)
     _attach_telemetry(sols, state, cfg)
+    if spans:
+        spans.lap(SOLVE_RECHECK)
     return sols, joint_errors
 
 
@@ -1234,14 +1266,16 @@ def vectorized_anneal(problem: FlatProblem, cluster: Cluster, goal: Goal,
 def _isolated_engine(batch: SolveBatch):
     sols = vectorized_anneal_many(batch.problems, batch.cluster, batch.goal,
                                   batch.cfg, batch.refs, goals=batch.goals,
-                                  bucket_p=batch.bucket_p, mesh=batch.mesh)
+                                  bucket_p=batch.bucket_p, mesh=batch.mesh,
+                                  spans=batch.spans)
     return sols, None
 
 
 def _shared_engine(batch: SolveBatch):
     return vectorized_anneal_shared(batch.problems, batch.cluster, batch.goal,
                                     batch.cfg, batch.refs, goals=batch.goals,
-                                    bucket_p=batch.bucket_p, mesh=batch.mesh)
+                                    bucket_p=batch.bucket_p, mesh=batch.mesh,
+                                    spans=batch.spans)
 
 
 register_engine(

@@ -77,6 +77,7 @@ from repro.obs import events as obs
 from repro.obs.aggregate import EventAggregator, finite_or_none
 from repro.obs.events import Event
 from repro.obs.sink import TagSink, TeeSink
+from repro.obs.spans import HTTP_DECODE, HTTP_ENCODE, recorder
 from repro.obs.trace import TraceIds
 
 __all__ = [
@@ -578,7 +579,8 @@ class PlannerService:
             flush_at, cause = self._flush_at(entry)
             now_v = self._now()
             if now_v >= flush_at:
-                self._flush(entry, cause)
+                # how late the timer fired: the loop's lag at this flush
+                self._flush(entry, cause, late_s=now_v - flush_at)
                 continue
             # sleep until the flush moment, but wake on any new submission
             # (it may fill the bucket or bring a tighter deadline)
@@ -589,7 +591,11 @@ class PlannerService:
             except asyncio.TimeoutError:
                 pass
 
-    def _flush(self, entry: _PoolEntry, cause: str) -> None:
+    def _flush(self, entry: _PoolEntry, cause: str, *,
+               late_s: float = 0.0) -> None:
+        """Hand up to ``max_batch`` queued requests to a dispatch task.
+        ``late_s`` is how far past its flush moment a timer flush
+        (``wait`` / ``deadline``) ran; 0 for ``fill`` and ``drain``."""
         batch = [entry.pending.popleft()
                  for _ in range(min(len(entry.pending), self.cfg.max_batch))]
         setattr(self.stats_counters, f"flush_{cause}",
@@ -600,7 +606,7 @@ class PlannerService:
             # repro.obs.trace for the two-granularity convention)
             self.sink.emit(Event(
                 obs.FLUSH, ts=self._now(), pool=entry.spec.name,
-                data={"cause": cause, "n": len(batch),
+                data={"cause": cause, "n": len(batch), "late_s": late_s,
                       "trace_ids": [p.request.trace for p in batch
                                     if p.request.trace]}))
         task = asyncio.create_task(
@@ -1064,6 +1070,20 @@ def metrics_text(stats: Dict[str, Any]) -> str:
            "gauge",
            [_prom("planner_convergence_accept_decay",
                   conv.get("accept_decay"))])
+    spans = ev_block.get("spans") or {}
+    family("planner_span_seconds_total",
+           "Host seconds in each span (solve phases, HTTP codec), by pool.",
+           "counter",
+           [_prom("planner_span_seconds_total", d.get("seconds"),
+                  {"pool": pool, "span": name})
+            for pool, per in sorted(spans.items())
+            for name, d in sorted(per.items())])
+    family("planner_spans_total", "Spans recorded, by pool and span.",
+           "counter",
+           [_prom("planner_spans_total", d.get("count"),
+                  {"pool": pool, "span": name})
+            for pool, per in sorted(spans.items())
+            for name, d in sorted(per.items())])
     pools = stats.get("pools") or {}
     family("planner_pool_pending", "Queued submissions per pool.", "gauge",
            [_prom("planner_pool_pending", p.get("pending"), {"pool": name})
@@ -1214,6 +1234,10 @@ class PlannerHTTPServer:
             # pre-rendered text body (the Prometheus exposition)
             body = payload.encode()
             ctype = "text/plain; version=0.0.4; charset=utf-8"
+        elif isinstance(payload, bytes):
+            # a plan, encoded where its http.encode span is timed
+            body = payload
+            ctype = "application/json"
         else:
             body = json.dumps(payload).encode()
             ctype = "application/json"
@@ -1261,7 +1285,7 @@ class PlannerHTTPServer:
         return (method, path, headers, body), None
 
     async def _respond(self, reader: asyncio.StreamReader
-                       ) -> Tuple[int, Union[dict, str]]:
+                       ) -> Tuple[int, Union[dict, str, bytes]]:
         # the timeout covers the READ only — a legitimate long-running
         # plan solve after parsing is not a slow client
         try:
@@ -1285,17 +1309,34 @@ class PlannerHTTPServer:
         if method == "POST" and path == "/v1/plan":
             if not self.service._running:
                 return 503, {"error": "service not running"}
+            # the codec's spans, on the loop thread (repro.obs.spans)
+            sink = self.service.sink
+            spans = recorder(sink)
+            if spans:
+                spans.mark()
             try:
                 obj = json.loads(body or b"{}")
                 request = request_from_json(obj)
             except (ValueError, KeyError, TypeError) as exc:
                 return 400, {"error": f"malformed request: {exc}"}
+            if spans:
+                spans.lap(HTTP_DECODE)
             try:
-                result = await self.service.submit(request,
-                                                   pool=obj.get("pool"))
+                # route here, once, so the codec's spans name the pool
+                pool = self.service._route(request, obj.get("pool")).spec.name
+                result = await self.service.submit(request, pool=pool)
             except LoadShedError as exc:
                 return 429, {"error": str(exc), "shed": True}
             except ValueError as exc:
                 return 400, {"error": str(exc)}
-            return 200, plan_result_to_json(result)
+            if spans:
+                spans.mark()
+            out = json.dumps(plan_result_to_json(result)).encode()
+            if spans:
+                spans.lap(HTTP_ENCODE)
+            if sink:
+                trace = result.request.trace if result.request else None
+                for event in spans.events(trace_ids=[trace], pool=pool):
+                    sink.emit(event)
+            return 200, out
         return 404, {"error": f"no route {method} {path}"}

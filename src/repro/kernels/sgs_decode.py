@@ -162,6 +162,9 @@ def sgs_decode(dur, dem, prio, release, pred, caps, *, T: int,
         out_specs=[row, row, row],
         out_shape=[jax.ShapeDtypeStruct((B, 1, Jp), f32)] * 3,
         interpret=interpret,
+        # one stable kernel name, batched or not: the device trace shows
+        # every call as sgs_decode.N
+        name="sgs_decode",
     )(durp, demT, priop, relp, predp, predp.T, capsc)
     return (start[:, 0, :J].astype(jnp.int32),
             finish[:, 0, :J].astype(jnp.int32), okc[:, 0, :J] > 0.0)

@@ -85,6 +85,15 @@ def render_events(path: str, snap: Dict[str, Any]) -> None:
     for pool, c in snap["pools"].items():
         print(f"  pool={pool}: "
               + " ".join(f"{k}={v}" for k, v in c.items()))
+    spans = snap.get("spans") or {}
+    if spans:
+        print(f"  host spans:  {'pool':<12} {'span':<14} {'count':>7} "
+              f"{'total s':>10} {'mean ms':>9}")
+        for pool, per in spans.items():
+            for name, d in per.items():
+                mean = 1e3 * d["seconds"] / d["count"] if d["count"] else 0
+                print(f"               {pool or '-':<12} {name:<14} "
+                      f"{d['count']:>7} {d['seconds']:>10.3f} {mean:>9.3f}")
     print(f"  tenants with terminal verdicts: {snap['tenants']}")
 
 

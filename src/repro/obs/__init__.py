@@ -30,6 +30,7 @@ from repro.obs.events import (
     POOL_RECOVERED,
     PREEMPT,
     SCHEMA_VERSION,
+    SPAN,
     CAPACITY_REVOKED,
     Event,
     event_from_json,
@@ -47,16 +48,18 @@ from repro.obs.sink import (
     as_sink,
     replay,
 )
+from repro.obs.spans import NULL_SPANS, NullSpans, Spans, recorder
 
 __all__ = [
     "ADMISSION_DECISION", "BUCKET_TRACED", "CACHE_HIT", "CAPACITY_AUDIT",
     "CAPACITY_REVOKED", "CAPACITY_VIOLATION", "DEADLINE_HIT",
     "DEADLINE_MISS", "DEFER", "DISPATCH", "DROP", "ENVELOPE_WIDENED",
     "EVENT_TYPES", "FAULT_INJECTED", "PLAN_SOLVED", "POOL_DEGRADED",
-    "POOL_RECOVERED", "PREEMPT", "SCHEMA_VERSION", "Event",
+    "POOL_RECOVERED", "PREEMPT", "SCHEMA_VERSION", "SPAN", "Event",
     "event_from_json", "read_jsonl",
     "NULL", "GuardedSink", "JsonlSink", "NullSink", "RingSink", "Sink",
     "TagSink", "TeeSink", "as_sink", "replay",
     "EventAggregator", "finite_or_none",
     "MISSING_ARTIFACT", "load_artifact", "missing_artifact",
+    "NULL_SPANS", "NullSpans", "Spans", "recorder",
 ]

@@ -17,7 +17,9 @@ What it derives (see docs/events.md for the event-type reference):
 * realized capacity headroom — elementwise min over ``capacity_audit``
   sweeps, plus the ``capacity_violation`` count;
 * p50/p99 submit-to-plan latency — the per-request wall latencies carried
-  on daemon ``dispatch`` events.
+  on daemon ``dispatch`` events;
+* host time by span — seconds and count of every ``span`` event (schema
+  v3) per ``(pool, span name)``: where a pool's solves and codec spend it.
 """
 from __future__ import annotations
 
@@ -80,6 +82,8 @@ class EventAggregator(Sink):
         # per-request convergence roll-ups from solve_profile events
         # (schema v2): the raw material of convergence_stats()
         self.profiles: List[Dict[str, Any]] = []
+        # (pool, span name) -> [seconds, count], from span events
+        self.spans: Dict[Tuple[str, str], List[float]] = {}
 
     # -- Sink ----------------------------------------------------------
 
@@ -142,6 +146,11 @@ class EventAggregator(Sink):
             self.degraded_pools.discard(e.pool or "")
             if pool is not None:
                 pool["recovered_events"] += 1
+        elif e.type == ev.SPAN:
+            acc = self.spans.setdefault(
+                (e.pool or "", str(e.data.get("name"))), [0.0, 0])
+            acc[0] += float(e.data.get("seconds", 0.0))
+            acc[1] += 1
         elif e.type == ev.CAPACITY_REVOKED:
             self.revocations += 1
         elif e.type == ev.CAPACITY_VIOLATION:
@@ -207,6 +216,16 @@ class EventAggregator(Sink):
             sum(float(p["accept_decay"]) for p in profiles) / len(profiles))
         return out
 
+    def span_totals(self) -> Dict[str, Dict[str, Dict[str, float]]]:
+        """``{pool: {span: {"seconds", "count"}}}`` over the span events
+        folded so far (empty before any)."""
+        with self._lock:
+            out: Dict[str, Dict[str, Dict[str, float]]] = {}
+            for (pool, name), (secs, n) in sorted(self.spans.items()):
+                out.setdefault(pool, {})[name] = {"seconds": secs,
+                                                  "count": n}
+            return out
+
     def snapshot(self) -> Dict[str, Any]:
         """One JSON-able roll-up: what ``/v1/stats`` serves under
         ``events`` and what ``obs_report`` prints."""
@@ -229,6 +248,7 @@ class EventAggregator(Sink):
                 "headroom": self.headroom,
                 "latency": self.latency_percentiles(),
                 "convergence": self.convergence_stats(),
+                "spans": self.span_totals(),
                 "pools": {name: dict(sorted(c.items()))
                           for name, c in sorted(self.pools.items())},
                 "tenants": len(self.tenants),

@@ -25,11 +25,13 @@ import dataclasses
 import json
 from typing import Any, Dict, Iterator, Mapping, Optional
 
-SCHEMA_VERSION = 2
+SCHEMA_VERSION = 3
 # older wire versions the reader still folds correctly: v1 events are a
 # strict subset of v2 (no trace_id/parent, no solve_profile type), so a
-# v1 tape reads as v2 with null causal fields. Anything else is foreign.
-SUPPORTED_SCHEMAS = frozenset({1, SCHEMA_VERSION})
+# v1 tape reads as v2 with null causal fields; v2 tapes lack only the
+# ``span`` type and carry fields v3 dropped as duplicates of
+# ``plan_solved`` (nothing folds them). Anything else is foreign.
+SUPPORTED_SCHEMAS = frozenset({1, 2, SCHEMA_VERSION})
 
 # event vocabulary (see docs/events.md for the per-type reference):
 #   solver / session layer
@@ -38,6 +40,7 @@ BUCKET_TRACED = "bucket_traced"        # a batch added a JIT cache entry
 CACHE_HIT = "cache_hit"                # a batch rode the live cache entry
 ADMISSION_DECISION = "admission_decision"  # session.admit verdict
 SOLVE_PROFILE = "solve_profile"        # in-solve convergence telemetry
+SPAN = "span"                          # one host span (repro.obs.spans)
 #   control plane / executor layer
 DISPATCH = "dispatch"                  # a planned batch handed to execution
 DEFER = "defer"                        # at-risk tenant waits for residue
@@ -63,6 +66,7 @@ EVENT_TYPES = (
     DISPATCH, DEFER, PREEMPT, DROP, CAPACITY_VIOLATION, CAPACITY_AUDIT,
     DEADLINE_HIT, DEADLINE_MISS, ENVELOPE_WIDENED, SUBMIT, FLUSH,
     FAULT_INJECTED, POOL_DEGRADED, POOL_RECOVERED, CAPACITY_REVOKED,
+    SPAN,
 )
 
 
@@ -75,7 +79,8 @@ class Event:
     * ``type``   — one of ``EVENT_TYPES``;
     * ``ts``     — seconds on the EMITTING layer's clock (the control
       plane's / daemon's virtual clock for flow events, ``time.monotonic``
-      for session-level solver events — see docs/events.md);
+      for session-level solver events and for every ``span``, wherever
+      emitted — see docs/events.md);
     * ``tenant`` / ``pool`` / ``sla`` — identity, where meaningful;
     * ``trace_id`` / ``parent`` — causal thread (schema v2): ``trace_id``
       groups every event one request caused across daemon → session →

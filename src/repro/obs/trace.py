@@ -112,7 +112,7 @@ def render_trace(events: Iterable[Event], trace_id: str) -> str:
         who = e.tenant or (f"batch[{len(member_ids(e))}]"
                            if member_ids(e) else "-")
         extras = []
-        for key in ("reason", "cause", "admitted", "bucket", "traced",
+        for key in ("name", "reason", "cause", "admitted", "bucket", "traced",
                     "warm", "n", "deadline", "completion", "steps_to_best",
                     "mode", "kind", "state", "delay_s", "degraded",
                     "killed", "caps_after"):

@@ -2,8 +2,8 @@
 
 The contracts the docs promise (docs/events.md):
 
-* wire schema v2 round-trips through JSON / JSON-lines bit-for-bit, a
-  committed v1 golden tape still folds identically, and the reader
+* wire schema v3 round-trips through JSON / JSON-lines bit-for-bit,
+  committed v1 and v2 golden tapes still fold identically, and the reader
   refuses streams from a foreign schema version;
 * causal traces reconstruct per-request span chains across both event
   granularities, and ``chain_complete`` gates on submit-root + terminal;
@@ -11,7 +11,8 @@ The contracts the docs promise (docs/events.md):
   outputs), on attaches ``ConvergenceTrace``s and emits ``solve_profile``
   exactly once per solve with zero warm-bucket retraces;
 * the disabled sink is FALSY and free — plans served with no sink are
-  bit-for-bit identical to plans served with a recording sink;
+  bit-for-bit identical to plans served with a recording sink, which
+  also records host spans (``span`` events, schema v3);
 * terminal ``deadline_hit`` / ``deadline_miss`` events are exactly-once
   per tenant across every streaming exit path (rejected at admission,
   dropped after plan retries, served);
@@ -194,22 +195,30 @@ def test_closed_jsonl_sink_drops_late_events_but_counts_them(tmp_path):
 
 
 def test_no_sink_plans_are_bit_identical_to_recorded_plans():
+    """Both engines: a recording sink (which also records the host spans
+    of every solve) and ``NullSink`` serve the same plans, bit for bit."""
     cluster = _cluster((4.0,))
     price = float(cluster.prices_per_sec[0])
     dags = [_chain_dag(f"d{i}", 3, 20.0, 1.0, 0.0, price) for i in range(3)]
-    ring = RingSink()
-    plain = _agora(cluster).session(shared_capacity=True, bucket_p=4)
-    taped = _agora(cluster).session(shared_capacity=True, bucket_p=4,
-                                    sink=ring)
-    assert not plain.sink
-    a = plain.plan([PlanRequest(dag=d) for d in dags])
-    b = taped.plan([PlanRequest(dag=d) for d in dags])
-    assert len(ring) > 0
-    for ra, rb in zip(a, b):
-        assert np.array_equal(ra.solution.option_idx, rb.solution.option_idx)
-        assert np.array_equal(ra.solution.start, rb.solution.start)
-        assert np.array_equal(ra.solution.finish, rb.solution.finish)
-        assert ra.solution.cost == rb.solution.cost
+    for shared in (True, False):
+        ring = RingSink()
+        plain = _agora(cluster).session(shared_capacity=shared, bucket_p=4,
+                                        sink=NullSink())
+        taped = _agora(cluster).session(shared_capacity=shared, bucket_p=4,
+                                        sink=ring)
+        assert not plain.sink
+        a = plain.plan([PlanRequest(dag=d) for d in dags])
+        b = taped.plan([PlanRequest(dag=d) for d in dags])
+        assert len(ring) > 0
+        names = [e.data["name"] for e in ring if e.type == ev.SPAN]
+        assert "solve.device" in names and "solve.recheck" in names
+        assert ("solve.select" in names) == shared
+        for ra, rb in zip(a, b):
+            assert np.array_equal(ra.solution.option_idx,
+                                  rb.solution.option_idx)
+            assert np.array_equal(ra.solution.start, rb.solution.start)
+            assert np.array_equal(ra.solution.finish, rb.solution.finish)
+            assert ra.solution.cost == rb.solution.cost
 
 
 class _BoobyTrappedSink(NullSink):
@@ -356,6 +365,8 @@ def test_daemon_stats_events_block_is_the_aggregator():
 
 GOLDEN_V1 = os.path.join(os.path.dirname(__file__), "golden",
                          "events_v1.jsonl")
+GOLDEN_V2 = os.path.join(os.path.dirname(__file__), "golden",
+                         "events_v2.jsonl")
 
 
 def test_v1_golden_tape_folds_identically_under_v2_reader():
@@ -374,6 +385,30 @@ def test_v1_golden_tape_folds_identically_under_v2_reader():
     assert old.hit_counts("guaranteed") == (1, 1)
     assert old.latency_percentiles()["p50"] == pytest.approx(0.2)
     assert old.headroom == [0.5, 1.0]
+
+
+def test_v2_golden_tape_folds_identically_under_v3_reader():
+    """v2 tapes carry fields v3 dropped as copies of ``plan_solved``
+    (``seconds`` on ``cache_hit`` / ``bucket_traced``, ``n`` / ``bucket``
+    / ``seconds`` on ``solve_profile``); no fold reads them, so the
+    committed v2 tape folds to the snapshot of its v3 equivalent."""
+    tape = list(read_jsonl(GOLDEN_V2))
+    assert tape and all(e.schema == 2 for e in tape)
+    dropped = {ev.CACHE_HIT: ("seconds",), ev.BUCKET_TRACED: ("seconds",),
+               ev.SOLVE_PROFILE: ("n", "bucket", "seconds")}
+    v3 = [Event(type=e.type, ts=e.ts, tenant=e.tenant, pool=e.pool,
+                sla=e.sla, trace_id=e.trace_id, parent=e.parent,
+                data={k: v for k, v in e.data.items()
+                      if k not in dropped.get(e.type, ())})
+          for e in tape]
+    assert all(e.schema == 3 for e in v3)
+    old, new = EventAggregator.fold(tape), EventAggregator.fold(v3)
+    assert old.snapshot() == new.snapshot()
+    assert (old.retraces, old.warmup_traces, old.cache_hits) == (1, 1, 1)
+    assert old.hit_counts("guaranteed") == (1, 1)
+    assert old.convergence_stats()["profiles"] == 1
+    assert old.snapshot()["spans"] == {}
+    assert chain_complete(spans(tape, "cafe0123-0000"))
 
 
 def test_foreign_schema_line_in_a_tape_is_refused_loudly(tmp_path):
