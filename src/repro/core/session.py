@@ -42,7 +42,7 @@ import numpy as np
 from repro.core.dag import DAG, FlatProblem, bucket_size, flatten
 from repro.core.objectives import Goal, Solution
 from repro.core.vectorized import (SolveBatch, SolveSpec, VecConfig,
-                                   resolve_engine)
+                                   decode_block, resolve_engine)
 from repro.obs import events as obs
 from repro.obs.aggregate import finite_or_none
 from repro.obs.events import Event
@@ -467,7 +467,16 @@ class PlannerSession:
         if spans:
             spans.lap(SOLVE_PREPARE)
         goals = [r.goal or self.goal for r in requests]
-        bucket_p = self.bucket_p if bucket_override is None else bucket_override
+        jmax, omax = _batch_shape(problems)
+        bucket_p = bucket_override
+        if bucket_p is None:
+            bucket_p = self.bucket_p
+            if bucket_p:
+                # a smaller batch rides a larger warmed bucket of its task
+                # shape rather than tracing its own (padded problems are
+                # inert, so the plans are the same)
+                bucket_p = self.warm_bucket(len(problems), jmax,
+                                            omax) or bucket_p
         batch = SolveBatch(
             spec=self.spec, problems=problems, cluster=cluster,
             goal=self.goal, goals=goals, refs=refs, cfg=self.vec_cfg,
@@ -490,7 +499,6 @@ class PlannerSession:
                 bucket_p = max(int(bucket_p or 1),
                                mesh.shape[mesh.axis_names[0]])
             bucket = bucket_size(len(problems), bucket_p)
-            jmax, omax = _batch_shape(problems)
             self._account(bucket, traced, dt, warming=warming)
             self.envelopes.add((bucket, jmax, omax))
 
@@ -499,9 +507,12 @@ class PlannerSession:
                  for s in sols]
         trace_ids = [r.trace for r in requests if r.trace is not None]
         if self.sink:
+            block = (decode_block(self.vec_cfg, mesh)
+                     if self.spec.engine_key in ("isolated", "shared")
+                     else None)
             self._emit_dispatch(traced, bucket=bucket, jmax=jmax,
                                 omax=omax, warming=warming,
-                                trace_ids=trace_ids)
+                                trace_ids=trace_ids, block=block)
             if not warming:
                 data = {"kind": "plan", "n": len(requests),
                         "bucket": bucket, "traced": traced, "seconds": dt}
@@ -535,14 +546,19 @@ class PlannerSession:
                        jmax: Optional[int] = None,
                        omax: Optional[int] = None,
                        warming: bool = False,
-                       trace_ids: Optional[List[str]] = None) -> None:
+                       trace_ids: Optional[List[str]] = None,
+                       block: Optional[Tuple[int, float]] = None) -> None:
         """Exactly one of ``bucket_traced`` / ``cache_hit`` per engine
-        dispatch (its wall seconds ride the ``plan_solved`` event)."""
+        dispatch (its wall seconds ride the ``plan_solved`` event).
+        ``block`` is the fused decode's ``(chains per grid step, padded-row
+        share)`` for the SA scan, where the kernel runs."""
         if not self.sink:
             return
         data = {"bucket": bucket, "warming": warming}
         if jmax is not None:
             data["jmax"], data["omax"] = jmax, omax
+        if block is not None:
+            data["decode_block"], data["decode_pad"] = block
         if trace_ids:
             data["trace_ids"] = list(trace_ids)
         self.sink.emit(Event(obs.BUCKET_TRACED if traced else obs.CACHE_HIT,
@@ -636,11 +652,22 @@ class PlannerSession:
         (without a mesh override; see ``_serve`` for the mesh case)."""
         return bucket_size(n, self.bucket_p)
 
+    def warm_bucket(self, n: int, jmax: int, omax: int) -> Optional[int]:
+        """The smallest already-traced bucket a batch of ``n`` requests
+        padding to task shape ``(jmax, omax)`` can be served at, or None.
+        With bucketing on, that is any warmed bucket of the shape at least
+        ``bucket_for(n)``; without it, ``n`` itself."""
+        b = self.bucket_for(n)
+        if not self.bucket_p:
+            return b if (b, jmax, omax) in self.envelopes else None
+        return min((w for w, j, o in self.envelopes
+                    if (j, o) == (jmax, omax) and w >= b), default=None)
+
     def is_warm(self, n: int, jmax: int, omax: int) -> bool:
         """True when a batch of ``n`` requests padding to task shape
         ``(jmax, omax)`` lands inside an already-traced signature — i.e.
         serving it re-traces nothing, by construction."""
-        return (self.bucket_for(n), jmax, omax) in self.envelopes
+        return self.warm_bucket(n, jmax, omax) is not None
 
     # -- one-shot joint planning (the legacy ``Agora.plan`` semantics) --
 

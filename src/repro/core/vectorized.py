@@ -43,6 +43,7 @@ from repro.core.objectives import Goal, Solution
 from repro.core.sgs import (schedule_cost, sgs_schedule,
                             validate_schedule_many)
 from repro.kernels import ops as kops
+from repro.kernels.sgs_decode import block_rows
 from repro.obs.spans import (NULL_SPANS, SOLVE_DEVICE, SOLVE_PACK,
                              SOLVE_RECHECK, SOLVE_SELECT, Spans)
 
@@ -242,6 +243,19 @@ def decode_schedule_batch(dp: DeviceProblem, option_idx, priority, *,
     return kops.sgs_decode(dur, dem, priority, dp.release_bins, dp.pred_mask,
                            dp.caps, T=dp.T, use_pallas=use_pallas,
                            interpret=interpret)
+
+
+def decode_block(cfg: VecConfig, mesh=None) -> Optional[Tuple[int, float]]:
+    """How the fused kernel blocks the SA scan's decode: ``(C, padded-row
+    share)`` for the chains one device decodes (a planner mesh splits them
+    over its second axis), or None where the reference decodes instead."""
+    if not kops.fused_decode(cfg.use_pallas):
+        return None
+    B = cfg.chains
+    if mesh is not None:
+        B //= mesh.shape[mesh.axis_names[1]]
+    C, Bp = block_rows(B)
+    return C, (Bp - B) / Bp
 
 
 def decode_schedule_full(dp: DeviceProblem, option_idx, priority, *,

@@ -22,6 +22,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def fused_decode(use_pallas: Optional[bool] = None) -> bool:
+    """Whether ``sgs_decode`` takes the fused kernel for this flag."""
+    return _on_tpu() if use_pallas is None else bool(use_pallas)
+
+
 def sgs_decode(dur, dem, prio, release, pred, caps, *, T: int,
                use_pallas: Optional[bool] = None,
                interpret: Optional[bool] = None):
@@ -33,9 +38,7 @@ def sgs_decode(dur, dem, prio, release, pred, caps, *, T: int,
       interpret   None = auto (compiled on TPU, interpreter elsewhere);
                   only consulted when the Pallas path is taken
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+    if fused_decode(use_pallas):
         if interpret is None:
             interpret = not _on_tpu()
         return _sgs_decode_pallas(dur, dem, prio, release, pred, caps,

@@ -5,20 +5,23 @@ chains (and, in shared-capacity mode, over P*Jmax flattened slots). The
 ``lax`` reference (kernels/ref.sgs_decode_ref) materializes the (T, M)
 usage tensor through HBM once per scan step; this kernel fuses the whole
 J-step loop — the demand-masked overload test, the window feasibility
-scan, the earliest-feasible-start argmax, and the usage-tensor window
-scatter — into ONE kernel invocation per chain, keeping usage resident
-in VMEM for the full placement loop.
+test, the earliest-feasible-start argmax, and the usage window scatter —
+into one kernel, keeping usage resident in VMEM for the whole loop.
 
-Two kernel-shaping choices:
+The placement loop is serial, so one chain alone leaves the vector unit
+mostly idle. Each grid step therefore decodes a block of C chains, one
+chain per sublane row: every per-chain quantity is a (C, ·) tile, and each
+vector op and cross-lane reduction of a placement serves C chains.
 
-* usage is held transposed, (M, T): resources on sublanes, time bins on
-  lanes (T is a multiple of 128 after padding), so the per-bin overload
-  test is a lane-wise VPU op;
-* the O(T) cumsum window test is re-expressed as a (T, T) mask-matmul
-  against the overload indicator (``win_bad = bad @ WT`` with
-  ``WT[s, t] = 1[t <= s < t+d]``), the same trick kernels/sched_energy.py
-  uses — integer counts are exact in f32, so feasibility verdicts are
-  bit-identical to the integer cumsum.
+* usage is held as M tiles of (C, Tp): chains on sublanes, time bins on
+  lanes (T is a multiple of 128 after padding);
+* the window test works per row, since each chain places a task of its
+  own duration d: a suffix-min over the overload bins gives, for every
+  start t, the next overloaded bin at or after t, and the window
+  [t, t + d) is free when that bin is >= t + d;
+* precedence runs over the problem's shared predecessor mask: a per-chain
+  count of unscheduled predecessors and a running ready time are updated
+  from the placed slot's successor row, an exact 0/1 matmul.
 
 All comparisons and the usage accumulation happen in the same dtype and
 order as the reference, so outputs are BIT-IDENTICAL, not merely close
@@ -28,96 +31,107 @@ masked reductions instead of dynamic gathers (Mosaic-friendly).
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_T = 128
+# Most chains one grid step decodes: the chip sweep of PERF.md §6
+# (benchmarks/bench_decode_block.py) at the served shapes.
+BLOCK_MAX = 256
+
+
+def block_rows(B: int) -> Tuple[int, int]:
+    """``(C, B_pad)`` for a decode of B chains: the fewest grid steps of at
+    most ``BLOCK_MAX`` chains each, C the sublane-aligned share of one
+    step, and B padded up to a multiple of C (by less than 8 a step).
+    Padded rows decode an empty chain and are sliced off."""
+    steps = -(-B // BLOCK_MAX)
+    C = -(-B // (8 * steps)) * 8
+    return C, steps * C
 
 
 def _first(mask, idx, n):
-    """Index of the first set lane of a (1, n) 0/1 row, as a (1, 1) f32 —
-    ``jnp.argmax``'s first-index tie-break without a 1-D argmax."""
+    """Per row, the index of the first set lane of a (C, n) mask, as a
+    (C, 1) f32 (``n`` where none is set) — ``jnp.argmax``'s first-index
+    tie-break without a 1-D argmax."""
     return jnp.min(jnp.where(mask, idx, float(n)), axis=1, keepdims=True)
 
 
-def _kernel(dur_ref, demT_ref, prio_ref, rel_ref, pred_ref, predT_ref,
-            caps_ref, start_ref, finish_ref, ok_ref, *, T: int, J: int):
-    """One chain per grid step. Every value is a 2-D f32 tile: per-slot
-    vectors are (1, Jp) rows, scalars (1, 1), usage (M, Tp). All integer
-    quantities (bins, slot indices) are small integers, exact in f32."""
-    Jp = dur_ref.shape[2]
-    M = demT_ref.shape[1]
+def _kernel(dur_ref, demT_ref, prio_ref, rel_ref, predT_ref, caps_ref,
+            start_ref, finish_ref, ok_ref, *, T: int, J: int):
+    """C chains per grid step, one per sublane row. Every value is a 2-D
+    f32 tile: per-slot state (C, Jp), per-chain scalars (C, 1), usage M x
+    (C, Tp). Bins and slot indices are small integers, exact in f32."""
+    C, M, Jp = demT_ref.shape
     Tp = -(-T // TILE_T) * TILE_T
-    dur = dur_ref[0]                                   # (1, Jp)
-    demT = demT_ref[0]                                 # (M, Jp)
-    prio = prio_ref[0]                                 # (1, Jp)
-    rel = rel_ref[...]                                 # (1, Jp)
-    pred = pred_ref[...]                               # (Jp, Jp) [j, p]
+    f32 = jnp.float32
+    dur = dur_ref[:, 0, :]                             # (C, Jp)
+    prio = prio_ref[:, 0, :]                           # (C, Jp)
+    dem = [demT_ref[:, m, :] for m in range(M)]        # M x (C, Jp)
+    # the reference's capacity threshold, rounded the same way (scalars)
+    lim = [caps_ref[m] + 1e-6 for m in range(M)]
     predT = predT_ref[...]                             # (Jp, Jp) [p, j]
-    caps = caps_ref[...]                               # (M, 1)
-    jrow = jax.lax.broadcasted_iota(jnp.int32, (1, Jp), 1).astype(jnp.float32)
-    jcol = jax.lax.broadcasted_iota(jnp.int32, (Jp, 1), 0).astype(jnp.float32)
-    trow = jax.lax.broadcasted_iota(jnp.int32, (1, Tp), 1).astype(jnp.float32)
-    scol = jax.lax.broadcasted_iota(jnp.int32, (Tp, 1), 0).astype(jnp.float32)
-    zero_row = jnp.zeros((1, Jp), jnp.float32)
+    jrow = jax.lax.broadcasted_iota(jnp.int32, (1, Jp), 1).astype(f32)
+    trow = jax.lax.broadcasted_iota(jnp.int32, (1, Tp), 1).astype(f32)
+    big = float(Tp)                                    # "no overloaded bin"
+    full = lambda row: jnp.broadcast_to(row, (C, Jp))
 
-    # scheduled flags are kept in both layouts: the row for selection, the
-    # column for the predecessor test over predT's sublanes
-    init = (jnp.zeros((M, Tp), jnp.float32),           # usage (transposed)
-            zero_row,                                  # finish
-            (jrow >= J).astype(jnp.float32),           # scheduled (padding on)
-            (jcol >= J).astype(jnp.float32),           # scheduled, column
-            zero_row,                                  # start
-            zero_row)                                  # placed_ok
+    init = (tuple(jnp.zeros((C, Tp), f32) for _ in range(M)),    # usage
+            full((jrow >= J).astype(f32)),             # scheduled (padding on)
+            full(jnp.sum(predT, axis=0, keepdims=True)),  # unscheduled preds
+            full(jnp.maximum(rel_ref[...], 0.0)),      # ready time per slot
+            jnp.zeros((C, Jp), f32),                   # start
+            jnp.zeros((C, Jp), f32))                   # placed_ok
 
     def body(_, carry):
-        usage, finish, sched, sched_c, start, okk = carry
-        blocked = jnp.max(predT * (1.0 - sched_c), axis=0, keepdims=True)
-        eligible = (sched == 0.0) & (blocked == 0.0)   # (1, Jp)
+        usage, sched, npred, ready_j, start, okk = carry
+        eligible = (sched == 0.0) & (npred == 0.0)
         score = jnp.where(eligible, prio, -jnp.inf)
         j = _first(score == jnp.max(score, axis=1, keepdims=True), jrow, Jp)
-        oh = jrow == j                                 # one-hot row over slots
-        oh_c = jcol == j                               # ... and as a column
-        d = jnp.sum(jnp.where(oh, dur, 0.0), axis=1, keepdims=True)     # (1, 1)
-        r = jnp.sum(jnp.where(oh, demT, 0.0), axis=1, keepdims=True)    # (M, 1)
-        predrow = jnp.max(jnp.where(oh_c, pred, 0.0), axis=0,
-                          keepdims=True)               # row j of pred, (1, Jp)
-        ready = jnp.maximum(
-            jnp.sum(jnp.where(oh, rel, 0.0), axis=1, keepdims=True),
-            jnp.max(jnp.where(predrow > 0.0, finish, 0.0), axis=1,
-                    keepdims=True))
-        over = (usage + r > caps + 1e-6) & (r > 0.0)   # (M, Tp)
-        bad = jnp.max(over.astype(jnp.float32), axis=0, keepdims=True)  # (1, Tp)
-        # window overload count on the MXU:
-        # win_bad[t] = sum_s bad[s] * WT[s, t], WT[s, t] = 1[t <= s < t + d];
-        # bad is broadcast to a full sublane tile for the matmul
-        WT = ((scol >= trow) & (scol < trow + d)).astype(jnp.float32)
-        win_bad = jax.lax.dot_general(
-            jnp.broadcast_to(bad, (8, Tp)), WT, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0:1, :]               # (1, Tp)
+        oh = jrow == j                                 # (C, Jp) one-hot
+        pick = lambda x: jnp.sum(jnp.where(oh, x, 0.0), axis=1, keepdims=True)
+        d = pick(dur)                                  # (C, 1)
+        ready = pick(ready_j)
+        r = [pick(x) for x in dem]                     # M x (C, 1)
+        bad = functools.reduce(jnp.logical_or, [
+            (u + rm > lm) & (rm > 0.0) for u, rm, lm in zip(usage, r, lim)])
+        # nxt[t] = first overloaded bin >= t (suffix min by doubling); the
+        # roll wraps, so bins that wrap around are masked out
+        nxt = jnp.where(bad, trow, big)
+        s = 1
+        while s < Tp:
+            ahead = pltpu.roll(nxt, Tp - s, 1)         # ahead[t] = nxt[t + s]
+            nxt = jnp.minimum(nxt, jnp.where(trow < Tp - s, ahead, big))
+            s *= 2
         # trow < T restricts candidates to the reference's [0, T) grid — for
         # d > 0 it is implied by t + d <= T, but a zero-duration (masked)
         # slot could otherwise land on the padded bin t == T
-        ok_t = ((win_bad == 0.0) & (trow >= ready) & (trow + d <= float(T))
+        ok_t = ((nxt >= trow + d) & (trow >= ready) & (trow + d <= float(T))
                 & (trow < float(T)))
-        any_ok = jnp.max(ok_t.astype(jnp.float32), axis=1, keepdims=True)
-        t_star = jnp.where(any_ok > 0.0, _first(ok_t, trow, Tp),
-                           jnp.maximum(ready, float(T) - d))
-        window = ((trow >= t_star) & (trow < t_star + d)).astype(jnp.float32)
-        usage = usage + window * r
-        finish = jnp.where(oh, t_star + d, finish)
+        t_ok = _first(ok_t, trow, Tp)
+        any_ok = t_ok < float(Tp)
+        t_star = jnp.where(any_ok, t_ok, jnp.maximum(ready, float(T) - d))
+        window = ((trow >= t_star) & (trow < t_star + d)).astype(f32)
+        usage = tuple(u + window * rm for u, rm in zip(usage, r))
+        # successors of the placed slot: one fewer unscheduled predecessor,
+        # and a ready time no earlier than its finish
+        succ = jnp.dot(oh.astype(f32), predT, preferred_element_type=f32)
+        npred = npred - succ
+        ready_j = jnp.where(succ > 0.0, jnp.maximum(ready_j, t_star + d),
+                            ready_j)
         sched = jnp.where(oh, 1.0, sched)
-        sched_c = jnp.where(oh_c, 1.0, sched_c)
         start = jnp.where(oh, t_star, start)
-        okk = jnp.where(oh, any_ok, okk)
-        return usage, finish, sched, sched_c, start, okk
+        okk = jnp.where(oh, any_ok.astype(f32), okk)
+        return usage, sched, npred, ready_j, start, okk
 
-    _, finish, _, _, start, okk = jax.lax.fori_loop(0, J, body, init)
-    start_ref[0] = start
-    finish_ref[0] = finish
-    ok_ref[0] = okk
+    *_, start, okk = jax.lax.fori_loop(0, J, body, init)
+    start_ref[:, 0, :] = start
+    finish_ref[:, 0, :] = start + dur
+    ok_ref[:, 0, :] = okk
 
 
 @functools.partial(jax.jit, static_argnames=("T", "interpret"))
@@ -125,46 +139,57 @@ def sgs_decode(dur, dem, prio, release, pred, caps, *, T: int,
                interpret: bool = False):
     """Fused batched grid-SGS decode. Same contract as
     kernels/ref.sgs_decode_ref: dur (B, J) i32, dem (B, J, M) f32,
-    prio (B, J) f32, release (J,) i32, pred (J, J) bool, caps (M,) f32
-    -> (start, finish (B, J) i32, ok (B, J) bool).
+    prio (B, J) f32, release (J,) i32, pred (J, J) bool (acyclic), caps
+    (M,) f32 -> (start, finish (B, J) i32, ok (B, J) bool).
 
     Pads J to a sublane multiple (padded slots are born "scheduled" and
     carry zero demand, so they can never be selected or shift a real
-    placement) and T to a TILE_T lane multiple (bins beyond T only ever
+    placement), T to a TILE_T lane multiple (bins beyond T only ever
     receive usage from truncation-free fallback placements, and no
     feasibility window that matters — every accepted window satisfies
-    ``t + d <= T`` — can read them).
+    ``t + d <= T`` — can read them), and B to ``block_rows(B)``'s multiple
+    of the block (padded chains are empty and sliced off).
 
-    Per-chain operands and outputs are laid out (B, 1, Jp) / (B, M, Jp) so
-    that every block's last two dims equal the array's (the TPU tiling
-    rule); bins and slot indices travel as f32, exact below 2**24.
+    Per-chain operands and outputs are laid out (B, 1, Jp) / (B, M, Jp),
+    blocked (C, 1, Jp) / (C, M, Jp), so that every block's last two dims
+    equal the array's (the TPU tiling rule); bins and slot indices travel
+    as f32, exact below 2**24.
     """
+    return decode_blocked(dur, dem, prio, release, pred, caps, T=T,
+                          C=block_rows(dur.shape[0])[0], interpret=interpret)
+
+
+def decode_blocked(dur, dem, prio, release, pred, caps, *, T: int, C: int,
+                   interpret: bool = False):
+    """``sgs_decode`` with the block of C chains per grid step given (a
+    multiple of 8): the served path derives it from B, a sweep of the block
+    size on the chip passes it."""
     B, J = dur.shape
     M = dem.shape[2]
     Jp = max(8, -(-J // 8) * 8)
+    Bp = -(-B // C) * C
     f32 = jnp.float32
-    durp = jnp.pad(dur.astype(f32), ((0, 0), (0, Jp - J)))[:, None, :]
-    demT = jnp.pad(dem.astype(f32),
-                   ((0, 0), (0, Jp - J), (0, 0))).transpose(0, 2, 1)
-    priop = jnp.pad(prio.astype(f32), ((0, 0), (0, Jp - J)))[:, None, :]
+    pad = ((0, Bp - B), (0, Jp - J))
+    durp = jnp.pad(dur.astype(f32), pad)[:, None, :]
+    demT = jnp.pad(dem.astype(f32), pad + ((0, 0),)).transpose(0, 2, 1)
+    priop = jnp.pad(prio.astype(f32), pad)[:, None, :]
     relp = jnp.pad(release.astype(f32), (0, Jp - J))[None, :]
-    predp = jnp.pad(pred.astype(f32), ((0, Jp - J), (0, Jp - J)))
-    capsc = caps.astype(f32)[:, None]
+    predT = jnp.pad(pred.astype(f32), ((0, Jp - J), (0, Jp - J))).T
 
-    row = pl.BlockSpec((1, 1, Jp), lambda b: (b, 0, 0))
+    rows = pl.BlockSpec((C, 1, Jp), lambda b: (b, 0, 0))
     whole = lambda shape: pl.BlockSpec(shape, lambda b: (0,) * len(shape))
     start, finish, okc = pl.pallas_call(
         functools.partial(_kernel, T=T, J=J),
-        grid=(B,),
-        in_specs=[row, pl.BlockSpec((1, M, Jp), lambda b: (b, 0, 0)), row,
-                  whole((1, Jp)), whole((Jp, Jp)), whole((Jp, Jp)),
-                  whole((M, 1))],
-        out_specs=[row, row, row],
-        out_shape=[jax.ShapeDtypeStruct((B, 1, Jp), f32)] * 3,
+        grid=(Bp // C,),
+        in_specs=[rows, pl.BlockSpec((C, M, Jp), lambda b: (b, 0, 0)), rows,
+                  whole((1, Jp)), whole((Jp, Jp)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[rows, rows, rows],
+        out_shape=[jax.ShapeDtypeStruct((Bp, 1, Jp), f32)] * 3,
         interpret=interpret,
         # one stable kernel name, batched or not: the device trace shows
         # every call as sgs_decode.N
         name="sgs_decode",
-    )(durp, demT, priop, relp, predp, predp.T, capsc)
-    return (start[:, 0, :J].astype(jnp.int32),
-            finish[:, 0, :J].astype(jnp.int32), okc[:, 0, :J] > 0.0)
+    )(durp, demT, priop, relp, predT, caps.astype(f32))
+    return (start[:B, 0, :J].astype(jnp.int32),
+            finish[:B, 0, :J].astype(jnp.int32), okc[:B, 0, :J] > 0.0)

@@ -7,6 +7,9 @@ the planner serves — ``VecConfig()``'s 256 chains x 600 iterations on a
 256-bin grid — with the fused decode forced on, and must contain the
 Pallas kernel (``tpu_custom_call``).
 """
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -21,9 +24,11 @@ from repro.core.dag import flatten
 from repro.core.objectives import Goal
 from repro.core.vectorized import (VecConfig, many_solve_call,
                                    shared_solve_call)
-from repro.kernels.sgs_decode import sgs_decode
+from repro.kernels.sgs_decode import block_rows, sgs_decode
 
 CFG = VecConfig(use_pallas=True, interpret=False)
+PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench")
 
 
 @pytest.fixture(scope="module")
@@ -72,6 +77,40 @@ def test_sgs_decode_compiles_for_v5e(one_chip):
             jnp.zeros((B, J), jnp.float32), jnp.zeros((J,), jnp.int32),
             jnp.zeros((J, J), bool), jnp.ones((M,), jnp.float32))
     _assert_kernel_compiles(sgs_decode, args, one_chip, T=T, interpret=False)
+
+
+@pytest.mark.parametrize("P,B,J,M", [
+    (8, 256, 7, 4),      # isolated pool: 8 paper tenants under vmap
+    (None, 256, 112, 2),  # shared pool: 8 x 14 Alibaba slots per chain
+    (None, 2, 112, 2),   # shared pool's 2-candidate selection decode
+    (None, 64, 112, 2),  # one chip of a (1, 4) mesh: chains sharded
+])
+def test_sgs_decode_served_widths_compile_for_v5e(one_chip, P, B, J, M):
+    """The kernel at each width it is served at compiles, and its custom
+    call keeps the shapes the benchmark reads the call's work from: the
+    first result's leading dims are the chains decoded (B padded to the
+    block), its last dim the padded slots, the demand operand's
+    second-to-last dim the resources."""
+    if PERFBENCH not in sys.path:
+        sys.path.insert(0, PERFBENCH)
+    from harness.layers import call_shape
+    from harness.trace import Op
+    T = 256
+    lead = () if P is None else (P,)
+    args = (jnp.zeros(lead + (B, J), jnp.int32),
+            jnp.zeros(lead + (B, J, M), jnp.float32),
+            jnp.zeros(lead + (B, J), jnp.float32),
+            jnp.zeros(lead + (J,), jnp.int32),
+            jnp.zeros(lead + (J, J), bool), jnp.ones((M,), jnp.float32))
+    fn = lambda *a: sgs_decode(*a, T=T)
+    if P is not None:
+        fn = jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None))
+    text = jax.jit(fn).lower(*_abstract(args, one_chip)).compile().as_text()
+    (line,) = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    op = Op(0.0, 0.0, "sgs_decode", line, 0)
+    assert call_shape(op) == ((P or 1) * block_rows(B)[1],
+                              -(-J // 8) * 8, M)
 
 
 def test_isolated_solve_compiles_for_v5e(one_chip):

@@ -185,6 +185,30 @@ def test_session_zero_retrace_inside_warmed_bucket(shared):
     assert math.isfinite(bs.steady_seconds)
 
 
+@pytest.mark.parametrize("shared", [False, True])
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_dispatch_event_records_decode_block(shared, use_pallas):
+    """Each dispatch event names how the fused kernel blocked the SA
+    scan's decode (chains per grid step, padded-row share); where the
+    reference decodes there is no block to name."""
+    from repro.obs import events as ev
+    from repro.obs.sink import RingSink
+    cfg = VecConfig(chains=12, iters=2, grid=64, seed=0,
+                    use_pallas=use_pallas, interpret=True)
+    sink = RingSink()
+    sess = _agora().session(shared_capacity=shared, bucket_p=2,
+                            vec_cfg=cfg, sink=sink)
+    sess.plan([PlanRequest(dag=d) for d in _random_dags(5, 2)])
+    (dispatch,) = [e for e in sink.events
+                   if e.type in (ev.BUCKET_TRACED, ev.CACHE_HIT)]
+    if use_pallas:
+        assert dispatch.data["decode_block"] == 16
+        assert dispatch.data["decode_pad"] == 0.25
+    else:
+        assert "decode_block" not in dispatch.data
+        assert "decode_pad" not in dispatch.data
+
+
 def test_session_capacity_snapshot_does_not_retrace():
     """Residual-capacity snapshots are traced arguments: narrowing the
     round's pool re-plans under the live cache entry."""
@@ -287,3 +311,23 @@ def test_admit_deadline_lower_bound():
     assert not late.admitted and "critical-path" in late.reason
     assert late.completion_lower_bound == pytest.approx(110.0)
     assert sess.stats.admitted == 1 and sess.stats.rejected == 1
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_small_batch_rides_larger_warmed_bucket(shared):
+    """With only a larger bucket warmed, a smaller batch of the same task
+    shape is served in it: nothing traces, and the plans are those of its
+    own bucket."""
+    dags = _random_dags(13, 4)
+    sess = _agora().session(shared_capacity=shared, bucket_p=True)
+    sess.warmup(dags[0], buckets=[4])
+    n0 = sess.stats.trace_count
+    assert sess.warm_bucket(2, J_TASKS, N_OPTS) == 4
+    assert sess.warm_bucket(2, J_TASKS + 1, N_OPTS) is None
+    res = sess.plan([PlanRequest(dag=d) for d in dags[:2]])
+    assert all(r.bucket == 4 and not r.traced for r in res)
+    assert sess.stats.trace_count == n0
+    own = _agora().session(shared_capacity=shared, bucket_p=True).plan(
+        [PlanRequest(dag=d) for d in dags[:2]])
+    assert all(r.bucket == 2 for r in own)
+    _assert_plans_equal([r.plan for r in own], res)

@@ -11,8 +11,12 @@ Three layers, all exact-equality (never allclose):
   must reproduce the default reference plans in all four solver modes —
   isolated/shared x bucketed/unbucketed.
 """
+import types
+
+import jax
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -26,6 +30,7 @@ from repro.core.objectives import Goal
 from repro.core.vectorized import (VecConfig, vectorized_anneal_many,
                                    vectorized_anneal_shared)
 from repro.kernels import ops, ref
+from repro.kernels.sgs_decode import BLOCK_MAX, block_rows, decode_blocked
 
 
 def _random_instance(rng, B, J, M, T, edge_density=0.15):
@@ -82,6 +87,99 @@ def test_decode_kernel_edge_cases():
             jnp.full((J,), T + 5, jnp.int32), z((J, J), bool),
             jnp.full((M,), 0.1, jnp.float32)]
     _assert_exact(args, T)
+
+
+@pytest.mark.parametrize("B", [1, 2, 9, 257])
+def test_decode_kernel_batch_not_a_block_multiple(B):
+    """B padded up to a multiple of the block: the padded chains are
+    sliced off and every real chain still matches."""
+    rng = np.random.default_rng(B)
+    _assert_exact(_random_instance(rng, B, 6, 2, 64), 64)
+
+
+def test_decode_kernel_block_rows_differ():
+    """One block whose rows place tasks of very different durations —
+    zero, short, exactly T and beyond T — and release times."""
+    T, J, M = 64, 7, 2
+    rng = np.random.default_rng(3)
+    dur, dem, prio, _, pred, caps = _random_instance(rng, 6, J, M, T)
+    dur = np.asarray(dur).copy()
+    dur[0] = 0                          # all masked
+    dur[1] = T                          # every task fills the grid
+    dur[2] = T + 9                      # longer than the grid
+    dur[3] = [0, 1, T, 2, T + 1, 5, 0]  # mixed within a row
+    dur[4] = 1
+    release = jnp.asarray([0, 3, T - 1, 5, 0, T, 2], jnp.int32)
+    _assert_exact([jnp.asarray(dur), dem, prio, release, pred, caps], T)
+
+
+def test_decode_kernel_priority_ties_across_rows():
+    """Rows that tie everywhere, inside a row and with each other: the
+    first-index tie-break must hold in every row of the block."""
+    T, J, M = 64, 6, 2
+    rng = np.random.default_rng(4)
+    dur, dem, _, release, pred, caps = _random_instance(rng, 10, J, M, T)
+    prio = np.zeros((10, J), np.float32)
+    prio[1::2, 1:4] = 0.5               # ties inside a row
+    prio[5] = prio[7] = 1.0             # identical rows
+    _assert_exact([dur, dem, jnp.asarray(prio), release, pred, caps], T)
+
+
+def test_decode_kernel_under_vmap_over_problems():
+    """The batched solves vmap the kernel over problems, each with its own
+    predecessor mask and release times (caps shared)."""
+    T, P = 64, 3
+    rng = np.random.default_rng(5)
+    insts = [_random_instance(rng, 9, 8, 3, T) for _ in range(P)]
+    args = [jnp.stack([inst[i] for inst in insts]) for i in range(5)]
+    caps = insts[0][5]
+    axes = (0, 0, 0, 0, 0, None)
+    r = jax.vmap(lambda *a: ref.sgs_decode_ref(*a, T=T), axes)(*args, caps)
+    k = jax.vmap(lambda *a: ops.sgs_decode(*a, T=T, use_pallas=True,
+                                           interpret=True), axes)(*args, caps)
+    for name, a, b in zip(("start", "finish", "ok"), r, k):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("B", [1, 2, 8, 9, 64, 255, BLOCK_MAX,
+                               BLOCK_MAX + 1, 3 * BLOCK_MAX - 5])
+def test_block_rows_rule(B):
+    """C follows B alone: the fewest grid steps of at most BLOCK_MAX
+    chains, C sublane-aligned, and B padded by less than 8 rows a step."""
+    C, B_pad = block_rows(B)
+    steps = -(-B // BLOCK_MAX)
+    assert C % 8 == 0 and C <= BLOCK_MAX and B_pad == steps * C
+    assert 0 <= B_pad - B < 8 * steps
+    if B <= BLOCK_MAX:
+        assert C == -(-B // 8) * 8
+
+
+def test_padded_rows_are_inert():
+    """However many empty chains pad the last block, the real chains'
+    decode is the reference's."""
+    T = 64
+    rng = np.random.default_rng(6)
+    args = _random_instance(rng, 9, 7, 2, T)
+    r = ref.sgs_decode_ref(*args, T=T)
+    for C in (8, 16, 24, 40):
+        k = decode_blocked(*args, T=T, C=C, interpret=True)
+        for name, a, b in zip(("start", "finish", "ok"), r, k):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{name} C={C}")
+
+
+def test_served_decode_block():
+    """The block the served SA scan's decode gets: from the chains one
+    device decodes, and none where the reference decodes."""
+    from repro.core.vectorized import decode_block
+    fused = VecConfig(use_pallas=True)
+    assert decode_block(fused) == block_rows(256)[:1] + (0.0,)
+    mesh = types.SimpleNamespace(axis_names=("prob", "chain"),
+                                 shape={"prob": 1, "chain": 4})
+    assert decode_block(fused, mesh) == (64, 0.0)
+    assert decode_block(VecConfig(chains=12, use_pallas=True)) == (16, 0.25)
+    assert decode_block(VecConfig(use_pallas=False)) is None
 
 
 @settings(max_examples=10, deadline=None)
