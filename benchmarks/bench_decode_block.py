@@ -11,12 +11,15 @@ reference itself goes wrong under ``vmap`` at J=7, see PERF.md §7):
   (paper m5 cluster, DAG1/DAG2);
 * ``shared``: 256 chains of 8 x 14 = 112 joint slots, M=2 (Alibaba);
 * ``shared_mesh``: the same on one chip of a (1, 4) mesh, 64 chains;
-* ``select``: the shared pool's 2-candidate selection decode.
+* ``select``: the shared pool's 2-candidate selection decode;
+* ``window``, ``window_select``: both at 64 x 14 = 896 joint slots, the
+  ``alibaba_v2018_window`` deployment. Joint shapes draw predecessors only
+  inside each tenant's 14 slots, as the shared pool's mask holds them.
 
 Each row gives the seconds of one call (the median of a few timings of 50
 calls run back to back in one jitted loop, so host dispatch is paid once)
 and the microseconds per placement per chain (call time over chains x J). ``block_rows``' pick
-for the shape is marked. On a host without a TPU it exits 2 and prints no
+for the shape is marked. ``--shapes`` and ``--blocks`` narrow the sweep. On a host without a TPU it exits 2 and prints no
 result.
 """
 from __future__ import annotations
@@ -42,18 +45,28 @@ T = 256
 BLOCKS = (8, 16, 32, 64, 128, 256)
 # name: (problems under vmap, chains, slots, resources)
 SHAPES = {"isolated": (8, 256, 7, 4), "shared": (1, 256, 112, 2),
-          "shared_mesh": (1, 64, 112, 2), "select": (1, 2, 112, 2)}
+          "shared_mesh": (1, 64, 112, 2), "select": (1, 2, 112, 2),
+          "window": (1, 256, 896, 2), "window_select": (1, 2, 896, 2)}
+# joint shapes: tenants of W slots each, whose predecessors stay inside
+# their own tenant (the shared pool's block-diagonal mask)
+TENANT = {"shared": 14, "shared_mesh": 14, "select": 14, "window": 14,
+          "window_select": 14}
 
 
-def instance(rng, P, B, J, M):
+def instance(rng, P, B, J, M, W=None):
     """Random decode inputs with per-problem DAGs: ``P`` stacked problems
-    of B chains over J slots (DAG edges point forward)."""
+    of B chains over J slots (DAG edges point forward; with ``W``, only
+    inside each tenant's block of W slots)."""
     dur = rng.integers(1, T // 8, (P, B, J)).astype(np.int32)
     dem = rng.uniform(0, 3, (P, B, J, M)).astype(np.float32)
     dem[:, :, ::3, :] = 0.0
     prio = rng.normal(size=(P, B, J)).astype(np.float32)
     release = rng.integers(0, T // 4, (P, J)).astype(np.int32)
-    pred = np.triu(rng.random((P, J, J)) < 2.0 / J, 1).transpose(0, 2, 1)
+    w = W or J
+    pred = np.triu(rng.random((P, J, J)) < 2.0 / w, 1).transpose(0, 2, 1)
+    if W:
+        tenant = np.arange(J) // W
+        pred &= tenant[None, :, None] == tenant[None, None, :]
     caps = rng.uniform(2, 6, (M,)).astype(np.float32)
     return [jnp.asarray(x) for x in (dur, dem, prio, release, pred, caps)]
 
@@ -90,6 +103,8 @@ def main() -> int:
     ap.add_argument("--out", default="BENCH_decode_block.json")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--shapes", nargs="+", default=list(SHAPES))
+    ap.add_argument("--blocks", type=int, nargs="+", default=list(BLOCKS))
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -97,17 +112,20 @@ def main() -> int:
         return 2
     rng = np.random.default_rng(a.seed)
     rows, ok_all = [], True
-    for name, (P, B, J, M) in SHAPES.items():
-        args = instance(rng, P, B, J, M)
+    for name in a.shapes:
+        P, B, J, M = SHAPES[name]
+        W = TENANT.get(name)
+        args = instance(rng, P, B, J, M, W)
         cpu = jax.devices("cpu")[0]
         with jax.default_device(cpu):
             ref = batched(lambda *x: sgs_decode_ref(*x, T=T))(
                 *[jax.device_put(x, cpu) for x in args])
+        ref = [np.asarray(x) for x in ref]
         rule, cap = block_rows(B)[0], -(-B // 8) * 8
-        for C in sorted({min(c, cap) for c in BLOCKS} | {rule}):
+        for C in sorted({min(c, cap) for c in a.blocks} | {rule}):
             fn = batched(lambda *x, C=C: decode_blocked(*x, T=T, C=C))
             out = fn(*args)
-            same = all(bool((np.asarray(x) == np.asarray(y)).all())
+            same = all(bool((np.asarray(x) == y).all())
                        for x, y in zip(out, ref))
             ok_all &= same
             s = timed(fn, args, a.reps)

@@ -109,7 +109,7 @@ def solve_checks(session, dags, failures, name):
     import numpy as np
 
     from repro.core.annealer import reference_point
-    from repro.core.dag import flatten
+    from repro.core.dag import concat_problems, flatten
     from repro.core.vectorized import (DeviceProblem, many_solve_call,
                                        shared_solve_call)
     cluster, cfg = session.cluster, session.vec_cfg
@@ -119,8 +119,9 @@ def solve_checks(session, dags, failures, name):
     ref_C = np.asarray([r[1] for r in refs])
     goals = [session.goal] * len(problems)
     if session.shared_capacity:
+        joint_ref = reference_point(concat_problems(problems), cluster)
         fn, args, sdp, _ = shared_solve_call(problems, cluster, cfg, ref_M,
-                                             ref_C, goals,
+                                             ref_C, goals, joint_ref,
                                              bucket_p=session.bucket_p)
         P_n, B, J = args[9].shape
         dp = sdp.dp

@@ -26,8 +26,9 @@ from repro.core.sgs import schedule_cost, sgs_schedule
 
 
 def _finish(problem, option_idx, start, finish, cluster, solver, t0,
-            optimal=False) -> Solution:
-    cost = schedule_cost(problem, option_idx, cluster.prices_per_sec)
+            optimal=False, durations=None, demands=None) -> Solution:
+    cost = schedule_cost(problem, option_idx, cluster.prices_per_sec,
+                         durations=durations, demands=demands)
     return Solution(option_idx, start, finish, float(finish.max()), cost,
                     solver=solver, solve_seconds=time.monotonic() - t0,
                     optimal_schedule=optimal)
@@ -39,9 +40,14 @@ def airflow_plan(problem: FlatProblem, cluster: Cluster) -> Solution:
     t0 = time.monotonic()
     option_idx = np.asarray([t.default_option for t in problem.tasks], np.int64)
     pr = problem.as_dag().downstream_counts().astype(float)
+    dur_all, dem_all, _, _ = problem.option_arrays()
+    tasks = np.arange(problem.num_tasks)
+    durations, demands = dur_all[tasks, option_idx], dem_all[tasks, option_idx]
     start, finish = sgs_schedule(problem, option_idx, priority=pr,
-                                 caps=cluster.caps)
-    return _finish(problem, option_idx, start, finish, cluster, "airflow", t0)
+                                 caps=cluster.caps, durations=durations,
+                                 demands=demands)
+    return _finish(problem, option_idx, start, finish, cluster, "airflow", t0,
+                   durations=durations, demands=demands)
 
 
 def _ernest_configs(problem: FlatProblem, goal_name: str) -> np.ndarray:

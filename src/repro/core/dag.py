@@ -297,7 +297,8 @@ def bucket_size(n: int, bucket_p) -> int:
 def pack_problems(problems: Sequence[FlatProblem],
                   num_resources: Optional[int] = None,
                   shared_capacity: bool = False,
-                  bucket_p=None) -> PackedProblems:
+                  bucket_p=None,
+                  shape: Optional[Tuple[int, int]] = None) -> PackedProblems:
     """Pad-and-stack P independent problems for one batched device solve.
 
     With ``shared_capacity=True`` the block-diagonal joint layout (every
@@ -308,7 +309,12 @@ def pack_problems(problems: Sequence[FlatProblem],
     axis is padded to a power-of-two bucket (see ``bucket_size``).  Padded
     problem slots are FULLY masked — zero tasks, zero durations/demands/
     costs, one dummy option, no edges — so a bucketed solve is bit-for-bit
-    identical to the unbucketed one for every real problem."""
+    identical to the unbucketed one for every real problem.
+
+    ``shape`` (Jmax, Omax) pads the task and option axes up to at least
+    that envelope (a warmed signature): padded task slots are masked like
+    those of a problem shorter than the batch's widest, padded options
+    repeat the last real one and are never drawn."""
     problems = list(problems)
     assert problems, "need at least one problem"
     if num_resources is None:
@@ -318,6 +324,8 @@ def pack_problems(problems: Sequence[FlatProblem],
     P = bucket_size(len(problems), bucket_p)
     Jmax = max(pr.num_tasks for pr in problems)
     Omax = max(max(len(t.options) for t in pr.tasks) for pr in problems)
+    if shape is not None:
+        Jmax, Omax = max(Jmax, shape[0]), max(Omax, shape[1])
     M = num_resources
 
     dur = np.zeros((P, Jmax, Omax))

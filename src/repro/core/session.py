@@ -468,21 +468,24 @@ class PlannerSession:
             spans.lap(SOLVE_PREPARE)
         goals = [r.goal or self.goal for r in requests]
         jmax, omax = _batch_shape(problems)
-        bucket_p = bucket_override
+        bucket_p, shape = bucket_override, (jmax, omax)
         if bucket_p is None:
             bucket_p = self.bucket_p
-            if bucket_p:
-                # a smaller batch rides a larger warmed bucket of its task
-                # shape rather than tracing its own (padded problems are
-                # inert, so the plans are the same)
-                bucket_p = self.warm_bucket(len(problems), jmax,
-                                            omax) or bucket_p
+            # a batch rides the smallest warmed envelope that holds it
+            # rather than tracing its own: padded problems and slots are
+            # inert, so its plans are valid, and those of its own envelope
+            # where the padding leaves the random draws' shapes alone
+            env = self.warm_envelope(len(problems), jmax, omax)
+            if env is not None:
+                shape = env[1:]
+                if bucket_p:
+                    bucket_p = env[0]
         batch = SolveBatch(
             spec=self.spec, problems=problems, cluster=cluster,
             goal=self.goal, goals=goals, refs=refs, cfg=self.vec_cfg,
             bucket_p=bucket_p, mesh=self._planner_mesh(),
             solve_single=lambda p, r, g: self._solve_single(p, r, g, cluster),
-            spans=spans)
+            spans=spans, shape=shape)
 
         with self._lock:
             n0 = self.engine.cache_size()
@@ -500,19 +503,21 @@ class PlannerSession:
                                mesh.shape[mesh.axis_names[0]])
             bucket = bucket_size(len(problems), bucket_p)
             self._account(bucket, traced, dt, warming=warming)
-            self.envelopes.add((bucket, jmax, omax))
+            self.envelopes.add((bucket,) + shape)
 
         convs = [ConvergenceTrace.from_telemetry(getattr(s, "telemetry",
                                                          None))
                  for s in sols]
         trace_ids = [r.trace for r in requests if r.trace is not None]
         if self.sink:
-            block = (decode_block(self.vec_cfg, mesh)
-                     if self.spec.engine_key in ("isolated", "shared")
-                     else None)
+            decode = None
+            if self.spec.engine_key in ("isolated", "shared"):
+                # serial placements per chain of one decode of the SA scan
+                slots = shape[0] * (bucket if self.shared_capacity else 1)
+                decode = (slots, decode_block(self.vec_cfg, mesh))
             self._emit_dispatch(traced, bucket=bucket, jmax=jmax,
                                 omax=omax, warming=warming,
-                                trace_ids=trace_ids, block=block)
+                                trace_ids=trace_ids, decode=decode)
             if not warming:
                 data = {"kind": "plan", "n": len(requests),
                         "bucket": bucket, "traced": traced, "seconds": dt}
@@ -547,18 +552,23 @@ class PlannerSession:
                        omax: Optional[int] = None,
                        warming: bool = False,
                        trace_ids: Optional[List[str]] = None,
-                       block: Optional[Tuple[int, float]] = None) -> None:
+                       decode: Optional[Tuple[int, Optional[Tuple[int, float]]]]
+                       = None) -> None:
         """Exactly one of ``bucket_traced`` / ``cache_hit`` per engine
         dispatch (its wall seconds ride the ``plan_solved`` event).
-        ``block`` is the fused decode's ``(chains per grid step, padded-row
-        share)`` for the SA scan, where the kernel runs."""
+        ``decode`` is, on a batched device engine, the SA scan's decode:
+        its serial placements per chain, and where the fused kernel runs
+        its ``(chains per grid step, padded-row share)``."""
         if not self.sink:
             return
         data = {"bucket": bucket, "warming": warming}
         if jmax is not None:
             data["jmax"], data["omax"] = jmax, omax
-        if block is not None:
-            data["decode_block"], data["decode_pad"] = block
+        if decode is not None:
+            slots, block = decode
+            data["decode_slots"] = slots
+            if block is not None:
+                data["decode_block"], data["decode_pad"] = block
         if trace_ids:
             data["trace_ids"] = list(trace_ids)
         self.sink.emit(Event(obs.BUCKET_TRACED if traced else obs.CACHE_HIT,
@@ -593,7 +603,7 @@ class PlannerSession:
         BEFORE traffic arrives; returns ``{bucket: wall_seconds}``.
 
         ``template`` fixes the task-shape envelope (Jmax, Omax): live
-        batches whose padded task shape matches the template's are then
+        batches whose padded task shape fits inside the template's are then
         served with zero re-tracing.  Default buckets: the session's
         minimum bucket; pass ``max_p`` to pre-pay every power of two up to
         it, or ``buckets`` explicitly."""
@@ -652,22 +662,24 @@ class PlannerSession:
         (without a mesh override; see ``_serve`` for the mesh case)."""
         return bucket_size(n, self.bucket_p)
 
-    def warm_bucket(self, n: int, jmax: int, omax: int) -> Optional[int]:
-        """The smallest already-traced bucket a batch of ``n`` requests
-        padding to task shape ``(jmax, omax)`` can be served at, or None.
-        With bucketing on, that is any warmed bucket of the shape at least
-        ``bucket_for(n)``; without it, ``n`` itself."""
+    def warm_envelope(self, n: int, jmax: int,
+                      omax: int) -> Optional[Tuple[int, int, int]]:
+        """The smallest already-traced envelope ``(bucket, Jmax, Omax)``
+        that holds a batch of ``n`` requests padding to task shape
+        ``(jmax, omax)``, or None: a task shape at least the batch's, and a
+        bucket of at least ``bucket_for(n)`` with bucketing on, ``n``
+        itself without it. Smallest: the fewest slots (bucket x Jmax)."""
         b = self.bucket_for(n)
-        if not self.bucket_p:
-            return b if (b, jmax, omax) in self.envelopes else None
-        return min((w for w, j, o in self.envelopes
-                    if (j, o) == (jmax, omax) and w >= b), default=None)
+        fits = [(w * j, w, j, o) for w, j, o in self.envelopes
+                if j >= jmax and o >= omax
+                and (w >= b if self.bucket_p else w == b)]
+        return min(fits)[1:] if fits else None
 
     def is_warm(self, n: int, jmax: int, omax: int) -> bool:
         """True when a batch of ``n`` requests padding to task shape
         ``(jmax, omax)`` lands inside an already-traced signature — i.e.
         serving it re-traces nothing, by construction."""
-        return self.warm_bucket(n, jmax, omax) is not None
+        return self.warm_envelope(n, jmax, omax) is not None
 
     # -- one-shot joint planning (the legacy ``Agora.plan`` semantics) --
 

@@ -10,12 +10,92 @@ order space; the annealers perturb priorities.
 """
 from __future__ import annotations
 
+import bisect
 import heapq
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.dag import FlatProblem
+
+
+class _UsageProfile:
+    """The capacity profile of the tasks placed so far: sorted breakpoints
+    (every placed task's start and finish) with the usage on each interval
+    ``[bps[k], bps[k + 1])`` as row k of ``use``; before the first
+    breakpoint and from the last one on, usage is zero.
+
+    ``add`` splits the intervals at the new task's start and finish (a split
+    copies the usage of the interval it cuts) and adds the task's demand to
+    the intervals it covers. Each row therefore holds, elementwise, the
+    demands of the tasks covering that interval summed in placement order
+    from zero — the order in which a scan over the placed tasks sums them
+    at any point of the interval — so the compared values are bit-for-bit
+    the scan's.
+    """
+
+    __slots__ = ("bps", "use", "starts", "finishes")
+
+    def __init__(self, J: int, M: int):
+        # rows for at most 2J breakpoints: every placed start and finish
+        self.bps: List[float] = []
+        self.use = np.zeros((2 * J + 1, M))
+        self.starts = np.zeros(2 * J + 1, bool)  # bps[k] is some task's start
+        self.finishes: List[float] = []          # every finish time, sorted
+
+    def _cut(self, t: float) -> int:
+        """The index of breakpoint ``t``, inserted if new."""
+        k = bisect.bisect_left(self.bps, t)
+        n = len(self.bps)
+        if k == n or self.bps[k] != t:
+            self.use[k + 1:n + 1] = self.use[k:n]
+            self.use[k] = self.use[k - 1] if k > 0 else 0.0
+            self.starts[k + 1:n + 1] = self.starts[k:n]
+            self.starts[k] = False
+            self.bps.insert(k, t)
+        return k
+
+    def add(self, s: float, f: float, r: np.ndarray) -> None:
+        i = self._cut(s)
+        j = self._cut(f)                          # after s: i stays
+        self.starts[i] = True
+        self.use[i:j] += r
+        bisect.insort(self.finishes, f)
+
+    def earliest_fit(self, t0: float, d: float, r: np.ndarray,
+                     lim: np.ndarray) -> float:
+        """Earliest start >= t0 where usage + r <= lim (``caps + 1e-9``)
+        throughout [t, t+d).
+
+        Candidates are t0 and then every finish time after t0, in order;
+        a candidate t fits when the usage at t and at every task start
+        strictly inside (t, t + d) leaves room for ``r``. When none fits,
+        the last candidate is returned."""
+        if not self.finishes or not r.any():
+            return t0
+        n = len(self.bps)
+        # every candidate lies at or after t0: only the intervals from the
+        # one holding t0 on are ever checked
+        base = max(bisect.bisect_right(self.bps, t0) - 1, 0)
+        bad = (self.use[base:n] + r > lim).any(axis=1)
+        bad_zero = bool((r > lim).any())          # before the first breakpoint
+        if not bad.any() and (t0 >= self.bps[0] or not bad_zero):
+            return t0
+        # checked points inside a window are task starts
+        bad_start = np.concatenate(
+            [[0], np.cumsum(bad & self.starts[base:n])])
+        i = bisect.bisect_right(self.finishes, t0)
+        t = t0
+        while True:
+            k0 = bisect.bisect_right(self.bps, t) - 1
+            if not (bad[k0 - base] if k0 >= 0 else bad_zero):
+                k1 = bisect.bisect_left(self.bps, t + d)
+                if bad_start[k1 - base] == bad_start[k0 + 1 - base]:
+                    return t
+            if i == len(self.finishes):
+                return t
+            t = self.finishes[i]
+            i += 1
 
 
 def sgs_schedule(problem: FlatProblem,
@@ -34,6 +114,7 @@ def sgs_schedule(problem: FlatProblem,
         demands = dem_all[np.arange(J), option_idx]
     if caps is None:
         caps = np.full(M, np.inf)
+    lim = np.asarray(caps) + 1e-9              # the scan's threshold, as it rounds
     if priority is None:
         priority = np.zeros(J)
 
@@ -48,36 +129,9 @@ def sgs_schedule(problem: FlatProblem,
 
     start = np.zeros(J)
     finish = np.zeros(J)
-    done = np.zeros(J, bool)
-    # running tasks as event list of (time, +/- demand)
-    events: List[Tuple[float, np.ndarray]] = []   # (finish_time, demand)
+    profile = _UsageProfile(J, M)
     ready = [(-priority[i], i) for i in range(J) if indeg[i] == 0]
     heapq.heapify(ready)
-    scheduled_any = []
-
-    def earliest_fit(t0: float, d: float, r: np.ndarray) -> float:
-        """Earliest start >= t0 where usage + r <= caps throughout [t, t+d)."""
-        if not events or not np.any(r):
-            return t0
-        evs = sorted(events, key=lambda e: e[0])
-        # candidate starts: t0 and each running-task finish time > t0
-        candidates = [t0] + [ft for ft, _ in evs if ft > t0]
-        active = [(s, f, dm) for (s, f, dm) in scheduled_any if f > t0]
-        for t in candidates:
-            ok = True
-            # check usage at every breakpoint within [t, t+d)
-            points = [t] + [s for (s, f, dm) in active if t < s < t + d]
-            for pt in points:
-                usage = np.zeros(len(caps))
-                for (s, f, dm) in active:
-                    if s <= pt < f:
-                        usage += dm
-                if np.any(usage + r > caps + 1e-9):
-                    ok = False
-                    break
-            if ok:
-                return t
-        return candidates[-1] if candidates else t0
 
     n_done = 0
     while ready:
@@ -85,12 +139,10 @@ def sgs_schedule(problem: FlatProblem,
         t_ready = max([problem.release[i]] + [finish[p] for p in preds[i]])
         d = float(durations[i])
         r = np.asarray(demands[i], float)
-        t = earliest_fit(t_ready, d, r)
+        t = profile.earliest_fit(t_ready, d, r, lim)
         start[i] = t
         finish[i] = t + d
-        events.append((t + d, r))
-        scheduled_any.append((t, t + d, r))
-        done[i] = True
+        profile.add(t, t + d, r)
         n_done += 1
         for j in succs[i]:
             indeg[j] -= 1

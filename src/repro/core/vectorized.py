@@ -38,14 +38,15 @@ from jax.sharding import PartitionSpec as P
 
 from repro.cluster.catalog import Cluster
 from repro.core.dag import (FlatProblem, PackedProblems, SharedCapacityLayout,
-                            pack_problems)
+                            concat_problems, pack_problems)
 from repro.core.objectives import Goal, Solution
 from repro.core.sgs import (schedule_cost, sgs_schedule,
                             validate_schedule_many)
 from repro.kernels import ops as kops
 from repro.kernels.sgs_decode import block_rows
 from repro.obs.spans import (NULL_SPANS, SOLVE_DEVICE, SOLVE_PACK,
-                             SOLVE_RECHECK, SOLVE_SELECT, Spans)
+                             SOLVE_RECHECK, SOLVE_REFPOINT, SOLVE_SELECT,
+                             Spans)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,7 +134,9 @@ class SolveBatch:
     sequential host engines loop over (built by the session so host
     fallbacks honor the same AnnealConfig / chains mesh the legacy front
     door used). ``spans`` records the device engines' host phases
-    (``repro.obs.spans``); the falsy default records nothing."""
+    (``repro.obs.spans``); the falsy default records nothing. ``shape``
+    is the warmed task-shape envelope the device engines pad the batch
+    up to (``pack_problems``)."""
     spec: SolveSpec
     problems: List[FlatProblem]
     cluster: Cluster
@@ -145,6 +148,7 @@ class SolveBatch:
     mesh: object = None
     solve_single: Optional[Callable] = None      # (problem, ref, goal) -> Solution
     spans: Spans = NULL_SPANS
+    shape: Optional[Tuple[int, int]] = None      # (Jmax, Omax) to pad up to
 
 
 @dataclasses.dataclass(frozen=True)
@@ -657,17 +661,20 @@ def _attach_telemetry(sols: List[Solution], state, cfg: VecConfig) -> None:
 
 def many_solve_call(problems: Sequence[FlatProblem], cluster: Cluster,
                     cfg: VecConfig, ref_M: np.ndarray, ref_C: np.ndarray,
-                    goals: Sequence[Goal], bucket_p=None, mesh=None):
+                    goals: Sequence[Goal], bucket_p=None, mesh=None,
+                    shape=None):
     """The device program of ``vectorized_anneal_many`` as ``(jitted fn,
     positional args)``: ``fn(*args)`` is the solve, and
-    ``fn.lower(*args).compile()`` compiles it ahead of time."""
+    ``fn.lower(*args).compile()`` compiles it ahead of time. ``shape``
+    pads the task shape up to a warmed envelope (``pack_problems``)."""
     if mesh is not None:
         ap, ac = mesh.axis_names
         # bucket the problem axis up to the mesh: power-of-two device
         # counts always divide the power-of-two bucket, and padded slots
         # are provably inert, so meshing never changes the plans
         bucket_p = max(int(bucket_p or 1), mesh.shape[ap])
-    packed = pack_problems(problems, cluster.num_resources, bucket_p=bucket_p)
+    packed = pack_problems(problems, cluster.num_resources, bucket_p=bucket_p,
+                           shape=shape)
     P_pad = packed.padded_problems
     if mesh is not None:
         assert P_pad % mesh.shape[ap] == 0, \
@@ -695,7 +702,8 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
                            refs: Optional[Sequence[Tuple[float, float]]] = None,
                            goals: Optional[Sequence[Goal]] = None,
                            bucket_p=None, mesh=None,
-                           spans: Spans = NULL_SPANS) -> List[Solution]:
+                           spans: Spans = NULL_SPANS,
+                           shape=None) -> List[Solution]:
     """Anneal P independent problems in one batched device solve.
 
     Returns one ``Solution`` per problem, each re-evaluated event-exactly on
@@ -731,7 +739,7 @@ def vectorized_anneal_many(problems: Sequence[FlatProblem], cluster: Cluster,
     ref_C = np.asarray([r[1] for r in refs])
 
     run, args = many_solve_call(problems, cluster, cfg, ref_M, ref_C, goals,
-                                bucket_p=bucket_p, mesh=mesh)
+                                bucket_p=bucket_p, mesh=mesh, shape=shape)
     if spans:
         spans.lap(SOLVE_PACK)
     state = run(*args)
@@ -1034,16 +1042,17 @@ def _run_sa_shared_sharded_jit(dp_arrays, dp_static, n_real, goal_w, ref_M,
 
 def shared_solve_call(problems: Sequence[FlatProblem], cluster: Cluster,
                       cfg: VecConfig, ref_M: np.ndarray, ref_C: np.ndarray,
-                      goals: Sequence[Goal], bucket_p=None, mesh=None):
+                      goals: Sequence[Goal], joint_ref: Tuple[float, float],
+                      bucket_p=None, mesh=None, shape=None):
     """The device program of ``vectorized_anneal_shared`` as ``(jitted fn,
     positional args, SharedDeviceProblem, joint FlatProblem)`` — see
-    ``many_solve_call``."""
-    from repro.core.annealer import reference_point
+    ``many_solve_call``. ``joint_ref`` is the joint problem's reference
+    point, ``reference_point(concat_problems(problems), cluster)``."""
     packed = pack_problems(problems, cluster.num_resources,
-                           shared_capacity=True, bucket_p=bucket_p)
+                           shared_capacity=True, bucket_p=bucket_p,
+                           shape=shape)
     layout = packed.shared_layout()
     joint = layout.joint_problem()
-    joint_ref = reference_point(joint, cluster)
     sdp = SharedDeviceProblem.build(layout, cluster, joint_ref[0], cfg)
     P_pad = packed.padded_problems
     ref_Mp, ref_Cp = _pad_refs(ref_M, ref_C, P_pad)
@@ -1070,7 +1079,7 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
                              refs: Optional[Sequence[Tuple[float, float]]] = None,
                              goals: Optional[Sequence[Goal]] = None,
                              bucket_p=None, mesh=None,
-                             spans: Spans = NULL_SPANS
+                             spans: Spans = NULL_SPANS, shape=None
                              ) -> Tuple[List[Solution], List[str]]:
     """Anneal P tenant problems against ONE shared cluster capacity.
 
@@ -1094,8 +1103,9 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     second axis — the coupled decode is joint over problems, so the first
     axis stays replicated here (see ``_run_sa_shared_sharded_jit``).
 
-    ``spans`` records ``solve.pack``, ``solve.device``, ``solve.select``
-    (the coupled re-evaluation of the two assemblies) and
+    ``spans`` records ``solve.refpoint`` (the joint reference point, which
+    sets the shared grid's resolution), ``solve.pack``, ``solve.device``,
+    ``solve.select`` (the coupled re-evaluation of the two assemblies) and
     ``solve.recheck``, which tile the call (see ``vectorized_anneal_many``).
     """
     cfg = cfg or VecConfig()
@@ -1112,10 +1122,13 @@ def vectorized_anneal_shared(problems: Sequence[FlatProblem], cluster: Cluster,
     assert len(goals) == len(problems)
     ref_M = np.asarray([r[0] for r in refs])
     ref_C = np.asarray([r[1] for r in refs])
+    joint_ref = reference_point(concat_problems(problems), cluster)
+    if spans:
+        spans.lap(SOLVE_REFPOINT)
 
     run, args, sdp, joint = shared_solve_call(
-        problems, cluster, cfg, ref_M, ref_C, goals, bucket_p=bucket_p,
-        mesh=mesh)
+        problems, cluster, cfg, ref_M, ref_C, goals, joint_ref,
+        bucket_p=bucket_p, mesh=mesh, shape=shape)
     if spans:
         spans.lap(SOLVE_PACK)
     state = run(*args)
@@ -1281,7 +1294,7 @@ def _isolated_engine(batch: SolveBatch):
     sols = vectorized_anneal_many(batch.problems, batch.cluster, batch.goal,
                                   batch.cfg, batch.refs, goals=batch.goals,
                                   bucket_p=batch.bucket_p, mesh=batch.mesh,
-                                  spans=batch.spans)
+                                  spans=batch.spans, shape=batch.shape)
     return sols, None
 
 
@@ -1289,7 +1302,7 @@ def _shared_engine(batch: SolveBatch):
     return vectorized_anneal_shared(batch.problems, batch.cluster, batch.goal,
                                     batch.cfg, batch.refs, goals=batch.goals,
                                     bucket_p=batch.bucket_p, mesh=batch.mesh,
-                                    spans=batch.spans)
+                                    spans=batch.spans, shape=batch.shape)
 
 
 register_engine(

@@ -42,6 +42,11 @@ TILE_T = 128
 # Most chains one grid step decodes: the chip sweep of PERF.md §6
 # (benchmarks/bench_decode_block.py) at the served shapes.
 BLOCK_MAX = 256
+# Scoped VMEM the kernel may use. A block of 256 chains at 896 joint slots
+# needs more than Mosaic's default limit on v5e, 16 MiB; v5e has 128 MiB of
+# VMEM, and at 64 MiB a block of BLOCK_MAX chains compiles for every joint
+# shape up to 1792 slots and 4 resources (PERF.md §6).
+VMEM_LIMIT = 64 << 20
 
 
 def block_rows(B: int) -> Tuple[int, int]:
@@ -187,6 +192,7 @@ def decode_blocked(dur, dem, prio, release, pred, caps, *, T: int, C: int,
         out_specs=[rows, rows, rows],
         out_shape=[jax.ShapeDtypeStruct((Bp, 1, Jp), f32)] * 3,
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         # one stable kernel name, batched or not: the device trace shows
         # every call as sgs_decode.N
         name="sgs_decode",

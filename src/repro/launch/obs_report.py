@@ -86,14 +86,20 @@ def render_events(path: str, snap: Dict[str, Any]) -> None:
         print(f"  pool={pool}: "
               + " ".join(f"{k}={v}" for k, v in c.items()))
     spans = snap.get("spans") or {}
+    slots = snap.get("decode_slots") or {}
     if spans:
+        # decode slots: the pool's mean serial placements per chain of one
+        # device decode, next to the host spans that scale with them
         print(f"  host spans:  {'pool':<12} {'span':<14} {'count':>7} "
-              f"{'total s':>10} {'mean ms':>9}")
+              f"{'total s':>10} {'mean ms':>9} {'decode slots':>12}")
         for pool, per in spans.items():
+            dslots = slots.get(pool)
+            col = "-" if dslots is None else f"{dslots:.1f}"
             for name, d in per.items():
                 mean = 1e3 * d["seconds"] / d["count"] if d["count"] else 0
                 print(f"               {pool or '-':<12} {name:<14} "
-                      f"{d['count']:>7} {d['seconds']:>10.3f} {mean:>9.3f}")
+                      f"{d['count']:>7} {d['seconds']:>10.3f} {mean:>9.3f} "
+                      f"{col:>12}")
     print(f"  tenants with terminal verdicts: {snap['tenants']}")
 
 

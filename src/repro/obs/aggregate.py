@@ -84,6 +84,8 @@ class EventAggregator(Sink):
         self.profiles: List[Dict[str, Any]] = []
         # (pool, span name) -> [seconds, count], from span events
         self.spans: Dict[Tuple[str, str], List[float]] = {}
+        # pool -> [decode slots, dispatches] of live device dispatches
+        self.decode_slots: Dict[str, List[int]] = {}
 
     # -- Sink ----------------------------------------------------------
 
@@ -97,6 +99,12 @@ class EventAggregator(Sink):
     def _fold(self, e: Event) -> None:
         self.counts[e.type] += 1
         pool = self._pool(e.pool) if e.pool is not None else None
+        if (e.type in (ev.BUCKET_TRACED, ev.CACHE_HIT)
+                and not e.data.get("warming")
+                and e.data.get("decode_slots") is not None):
+            acc = self.decode_slots.setdefault(e.pool or "", [0, 0])
+            acc[0] += int(e.data["decode_slots"])
+            acc[1] += 1
         if e.type == ev.BUCKET_TRACED:
             if e.data.get("warming"):
                 self.warmup_traces += 1
@@ -226,6 +234,13 @@ class EventAggregator(Sink):
                                                   "count": n}
             return out
 
+    def decode_slot_means(self) -> Dict[str, float]:
+        """``{pool: mean serial placements per chain of one decode}`` over
+        the live device dispatches folded so far (empty before any)."""
+        with self._lock:
+            return {pool: tot / n for pool, (tot, n)
+                    in sorted(self.decode_slots.items())}
+
     def snapshot(self) -> Dict[str, Any]:
         """One JSON-able roll-up: what ``/v1/stats`` serves under
         ``events`` and what ``obs_report`` prints."""
@@ -249,6 +264,7 @@ class EventAggregator(Sink):
                 "latency": self.latency_percentiles(),
                 "convergence": self.convergence_stats(),
                 "spans": self.span_totals(),
+                "decode_slots": self.decode_slot_means(),
                 "pools": {name: dict(sorted(c.items()))
                           for name, c in sorted(self.pools.items())},
                 "tenants": len(self.tenants),

@@ -30,6 +30,7 @@ from repro.obs.events import SPAN, Event
 
 # span vocabulary (docs/events.md): the served solve, executor thread —
 SOLVE_PREPARE = "solve.prepare"    # flatten + reference point per request
+SOLVE_REFPOINT = "solve.refpoint"  # shared pools: joint reference point
 SOLVE_PACK = "solve.pack"          # pack, device arrays, initial chains
 SOLVE_DEVICE = "solve.device"      # device solve through its blocking fetch
 SOLVE_SELECT = "solve.select"      # shared pools: pick one of two assemblies

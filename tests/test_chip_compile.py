@@ -20,11 +20,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.cluster.catalog import alibaba_cluster, paper_cluster
 from repro.cluster.workloads import dag1, dag2, synth_trace
 from repro.core.annealer import reference_point
-from repro.core.dag import flatten
+from repro.core.dag import concat_problems, flatten
 from repro.core.objectives import Goal
 from repro.core.vectorized import (VecConfig, many_solve_call,
                                    shared_solve_call)
-from repro.kernels.sgs_decode import block_rows, sgs_decode
+from repro.kernels.sgs_decode import BLOCK_MAX, block_rows, sgs_decode
 
 CFG = VecConfig(use_pallas=True, interpret=False)
 PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -64,6 +64,10 @@ def _refs(problems, cluster):
     refs = [reference_point(p, cluster) for p in problems]
     return (np.asarray([r[0] for r in refs]),
             np.asarray([r[1] for r in refs]))
+
+
+def _joint_ref(problems, cluster):
+    return reference_point(concat_problems(problems), cluster)
 
 
 def _assert_kernel_compiles(fn, args, one_chip, **static):
@@ -113,6 +117,31 @@ def test_sgs_decode_served_widths_compile_for_v5e(one_chip, P, B, J, M):
                               -(-J // 8) * 8, M)
 
 
+@pytest.mark.parametrize("B,J,M", [(256, 896, 2), (2, 896, 2),
+                                   (256, 1792, 2), (256, 896, 4)])
+def test_sgs_decode_window_widths_compile_for_v5e(one_chip, B, J, M):
+    """Joint decodes of 64 tenants x 14 slots = 896 slots (the window's SA
+    scan, 256 chains, and its selection decode, 2) and beyond, to 128
+    tenants and to 4 resources: a block of up to BLOCK_MAX chains fits the
+    kernel's scoped VMEM limit in one grid step, and the custom call keeps
+    the shapes the benchmark reads."""
+    if PERFBENCH not in sys.path:
+        sys.path.insert(0, PERFBENCH)
+    from harness.layers import call_shape
+    from harness.trace import Op
+    T = 256
+    args = (jnp.zeros((B, J), jnp.int32), jnp.zeros((B, J, M), jnp.float32),
+            jnp.zeros((B, J), jnp.float32), jnp.zeros((J,), jnp.int32),
+            jnp.zeros((J, J), bool), jnp.ones((M,), jnp.float32))
+    fn = lambda *a: sgs_decode(*a, T=T)
+    text = jax.jit(fn).lower(*_abstract(args, one_chip)).compile().as_text()
+    (line,) = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    C, Bp = block_rows(B)
+    assert C == min(Bp, BLOCK_MAX) and Bp == (256 if B == 256 else 8)
+    assert call_shape(Op(0.0, 0.0, "sgs_decode", line, 0)) == (Bp, J, M)
+
+
 def test_isolated_solve_compiles_for_v5e(one_chip):
     """P=8 paper-cluster tenants (DAG1/DAG2, M=4) in one batched solve."""
     cluster = paper_cluster()
@@ -124,6 +153,21 @@ def test_isolated_solve_compiles_for_v5e(one_chip):
     _assert_kernel_compiles(fn, args, one_chip)
 
 
+def test_window_shared_solve_compiles_for_v5e(one_chip):
+    """P=64 Alibaba tenants of up to 14 tasks in one 896-slot joint decode,
+    the ``alibaba_v2018_window`` deployment's solve."""
+    cluster = alibaba_cluster()
+    problems = [flatten([d], cluster.num_resources)
+                for d in synth_trace(64, cluster, seed=3)]
+    ref_M, ref_C = _refs(problems, cluster)
+    fn, args, sdp, _ = shared_solve_call(problems, cluster, CFG, ref_M,
+                                         ref_C, [Goal.balanced()] * 64,
+                                         _joint_ref(problems, cluster),
+                                         bucket_p=64, shape=(14, 6))
+    assert sdp.dp.dur_bins.shape[0] == 64 * 14
+    _assert_kernel_compiles(fn, args, one_chip)
+
+
 def test_shared_solve_compiles_for_v5e(one_chip):
     """P=8 Alibaba §5.5 tenants (6-14 tasks, M=2) coupled through one
     usage tensor: P * Jmax slots per decode."""
@@ -132,5 +176,7 @@ def test_shared_solve_compiles_for_v5e(one_chip):
                 for d in synth_trace(8, cluster, seed=3)]
     ref_M, ref_C = _refs(problems, cluster)
     fn, args, _, _ = shared_solve_call(problems, cluster, CFG, ref_M, ref_C,
-                                       [Goal.balanced()] * 8, bucket_p=8)
+                                       [Goal.balanced()] * 8,
+                                       _joint_ref(problems, cluster),
+                                       bucket_p=8)
     _assert_kernel_compiles(fn, args, one_chip)

@@ -322,8 +322,8 @@ def test_small_batch_rides_larger_warmed_bucket(shared):
     sess = _agora().session(shared_capacity=shared, bucket_p=True)
     sess.warmup(dags[0], buckets=[4])
     n0 = sess.stats.trace_count
-    assert sess.warm_bucket(2, J_TASKS, N_OPTS) == 4
-    assert sess.warm_bucket(2, J_TASKS + 1, N_OPTS) is None
+    assert sess.warm_envelope(2, J_TASKS, N_OPTS)[0] == 4
+    assert sess.warm_envelope(2, J_TASKS + 1, N_OPTS) is None
     res = sess.plan([PlanRequest(dag=d) for d in dags[:2]])
     assert all(r.bucket == 4 and not r.traced for r in res)
     assert sess.stats.trace_count == n0
@@ -331,3 +331,69 @@ def test_small_batch_rides_larger_warmed_bucket(shared):
         [PlanRequest(dag=d) for d in dags[:2]])
     assert all(r.bucket == 2 for r in own)
     _assert_plans_equal([r.plan for r in own], res)
+
+
+def _shaped_dag(seed, J, O):
+    """A random DAG of J tasks with O options each (M_RES resources)."""
+    rng = np.random.default_rng(seed)
+    tasks = []
+    for j in range(J):
+        opts = []
+        for o in range(O):
+            d = float(rng.uniform(5, 40))
+            dem = tuple(float(x) for x in rng.uniform(0.1, 2.0, M_RES))
+            opts.append(TaskOption(f"o{o}", d, dem, d * sum(dem)))
+        tasks.append(Task(f"t{j}", opts,
+                          default_option=int(rng.integers(0, O))))
+    edges = [(a, b) for a in range(J) for b in range(a + 1, J)
+             if rng.random() < 0.25]
+    return DAG(f"s{seed}", tasks, edges)
+
+
+def test_warm_envelope_is_the_smallest_that_holds_the_batch():
+    sess = _agora().session(shared_capacity=True, bucket_p=True)
+    sess.envelopes.update({(4, 7, 3), (2, 9, 3), (8, 5, 2), (2, 5, 4)})
+    assert sess.warm_envelope(2, 5, 2) == (2, 5, 4)     # fewest slots
+    assert sess.warm_envelope(2, 6, 2) == (2, 9, 3)     # 18 slots, not 28
+    assert sess.warm_envelope(3, 8, 3) is None          # no bucket 4 of J 9
+    assert sess.warm_envelope(5, 5, 2) == (8, 5, 2)
+    assert sess.warm_envelope(1, 10, 1) is None
+    assert sess.warm_envelope(3, 6, 2)[0] == 4 and sess.is_warm(3, 6, 2)
+    flat = _agora().session(shared_capacity=True, bucket_p=None)
+    flat.envelopes.update({(2, 7, 3), (4, 5, 2)})
+    assert flat.warm_envelope(2, 5, 2) == (2, 7, 3)     # bucket exact
+    assert flat.warm_envelope(3, 5, 2) is None
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_batch_rides_a_wider_warmed_envelope(shared):
+    """A batch whose DAGs are narrower than every warmed envelope rides the
+    smallest one that holds it: nothing traces, no exit, valid plans."""
+    tmpl = _shaped_dag(0, 7, 3)
+    sess = _agora().session(shared_capacity=shared, bucket_p=True)
+    sess.warmup(tmpl, buckets=[4])
+    n0 = sess.stats.trace_count
+    dags = [_shaped_dag(s, J, 2) for s, J in ((1, 4), (2, 6), (3, 5))]
+    assert sess.warm_envelope(3, 6, 2) == (4, 7, 3)
+    res = sess.plan([PlanRequest(dag=d) for d in dags])
+    assert sess.stats.trace_count == n0
+    assert all(r.bucket == 4 and not r.traced for r in res)
+    for r, d in zip(res, dags):
+        assert r.plan.validate() == []
+        assert len(r.plan.solution.start) == d.num_tasks
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_wider_option_envelope_is_bit_identical(shared):
+    """Padding only the option axis leaves every random draw's shape as it
+    was (chains x slots): the plans equal those of the exact envelope."""
+    dags = [_shaped_dag(s, 5, 2) for s in range(3)]
+    wide = _agora().session(shared_capacity=shared, bucket_p=True)
+    wide.warmup(_shaped_dag(9, 5, 4), buckets=[4])
+    n0 = wide.stats.trace_count
+    got = wide.plan([PlanRequest(dag=d) for d in dags])
+    assert wide.stats.trace_count == n0
+    exact = _agora().session(shared_capacity=shared, bucket_p=True)
+    exact.warmup(dags[0], buckets=[4])
+    want = exact.plan([PlanRequest(dag=d) for d in dags])
+    _assert_plans_equal([r.plan for r in want], got)

@@ -230,3 +230,40 @@ def test_fused_plans_match_reference_shared():
             np.testing.assert_array_equal(x.option_idx, y.option_idx)
             np.testing.assert_array_equal(x.start, y.start)
             np.testing.assert_array_equal(x.finish, y.finish)
+
+
+def _joint_instance(rng, B, P, W, M, T):
+    """A shared pool's joint layout: P tenants of W slots, predecessors only
+    inside a tenant, with zero-duration and zero-demand slots, padding
+    priorities and ties."""
+    J = P * W
+    dur = rng.integers(0, max(T // 3, 1), (B, J)).astype(np.int32)
+    dur[:, ::5] = 0
+    dem = rng.uniform(0, 3, (B, J, M)).astype(np.float32)
+    dem[:, ::3, :] = 0.0
+    prio = rng.integers(0, 4, (B, J)).astype(np.float32)   # many ties
+    prio[:, W - 1::W] = -1e9
+    release = rng.integers(0, T // 2, (J,)).astype(np.int32)
+    pred = np.zeros((J, J), bool)
+    for p in range(P):
+        for _ in range(2 * W):
+            a, b = rng.integers(0, W, 2)
+            if a < b:
+                pred[p * W + b, p * W + a] = True
+    caps = rng.uniform(1.0, 6, (M,)).astype(np.float32)
+    return [jnp.asarray(x) for x in (dur, dem, prio, release, pred, caps)]
+
+
+@pytest.mark.parametrize("P,B", [(1, 3), (8, 9), (64, 2), (64, 4)])
+def test_decode_kernel_joint_layouts_bit_exact(P, B):
+    """The joint decode of P tenants of 14 slots, as the shared pool runs
+    it, against the reference: exact for one tenant, for 8 (112 slots) and
+    for 64 (896 slots), for the SA scan's few chains and the selection
+    decode's 2."""
+    T, W, M = 64, 14, 2
+    args = _joint_instance(np.random.default_rng(P * 10 + B), B, P, W, M, T)
+    r = ref.sgs_decode_ref(*args, T=T)
+    k = ops.sgs_decode(*args, T=T, use_pallas=True, interpret=True)
+    for name, a, b in zip(("start", "finish", "ok"), r, k):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)

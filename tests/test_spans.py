@@ -34,8 +34,8 @@ from repro.obs.events import Event
 from repro.obs.sink import JsonlSink, NullSink, RingSink, replay
 
 CFG = VecConfig(chains=8, iters=40, grid=64, seed=0)
-SOLVE_SPANS = {sp.SOLVE_PREPARE, sp.SOLVE_PACK, sp.SOLVE_DEVICE,
-               sp.SOLVE_SELECT, sp.SOLVE_RECHECK}
+SOLVE_SPANS = {sp.SOLVE_PREPARE, sp.SOLVE_REFPOINT, sp.SOLVE_PACK,
+               sp.SOLVE_DEVICE, sp.SOLVE_SELECT, sp.SOLVE_RECHECK}
 
 
 class _NoClock:
@@ -153,7 +153,10 @@ def test_solve_spans_tile_plan_solved(shared):
         ids = ps.data["trace_ids"]
         mine = [e for e in spans if e.data["trace_ids"] == ids]
         names = [e.data["name"] for e in mine]
-        want = [sp.SOLVE_PREPARE, sp.SOLVE_PACK, sp.SOLVE_DEVICE]
+        # shared pools lap the joint reference point ahead of the pack;
+        # isolated pools lap as they did
+        want = [sp.SOLVE_PREPARE] + ([sp.SOLVE_REFPOINT] if shared else [])
+        want += [sp.SOLVE_PACK, sp.SOLVE_DEVICE]
         want += [sp.SOLVE_SELECT] if shared else []
         assert names == want + [sp.SOLVE_RECHECK]
         # each span emitted once, after its plan_solved, on the pool clock
@@ -261,3 +264,30 @@ def test_aggregator_folds_spans_per_pool_and_obs_report_prints_them(
     out = capsys.readouterr().out
     assert "host spans" in out
     assert "solve.pack" in out and "375.000" in out   # mean ms of pool a
+    assert "decode slots" in out
+
+
+def test_dispatch_events_carry_decode_slots_and_obs_report_prints_them(
+        tmp_path, capsys):
+    """``decode_slots`` is the SA scan's serial placements per chain:
+    bucket x Jmax in a joint decode, Jmax in an isolated one; warm-up
+    dispatches carry it but do not count in the operator's mean."""
+    for shared, want in ((False, 3), (True, 4 * 3)):
+        ring = RingSink()
+        sess = _agora(_cluster()).session(shared_capacity=shared,
+                                          bucket_p=4, sink=ring)
+        sess.warmup(_chain_dag("tmpl"), buckets=[4])
+        sess.plan([PlanRequest(dag=_chain_dag(f"d{i}"), trace=f"t{i}")
+                   for i in range(2)])
+        disp = [e for e in ring if e.type in (ev.BUCKET_TRACED,
+                                             ev.CACHE_HIT)]
+        assert [e.data["decode_slots"] for e in disp] == [want, want]
+        agg = EventAggregator.fold(ring)
+        assert agg.snapshot()["decode_slots"] == {"": float(want)}
+    path = tmp_path / "events.jsonl"
+    with JsonlSink(str(path)) as sink:
+        replay(list(ring) + [_span(None, sp.SOLVE_PACK, 0.5)], sink)
+    assert obs_report.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    row = [ln for ln in out.splitlines() if "solve.pack" in ln]
+    assert row and row[0].split()[-1] == "12.0"
